@@ -18,6 +18,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (common, copy_stencil, dryrun_table, dycore_fused,
                             energy, kernel_walltime, pe_scaling,
                             roofline_kernels, serve_forecast, table3,

@@ -153,9 +153,8 @@ from repro.weather import domain, fields
 from repro.weather import program as wprog
 from repro.weather.program import StencilProgram
 
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
-mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 grid = (4, 16, 16)
 prog = StencilProgram(grid_shape=grid, ensemble=1)
 states = [fields.initial_state(jax.random.PRNGKey(s), grid, ensemble=1)

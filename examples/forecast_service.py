@@ -39,15 +39,15 @@ from repro.testing.faults import FaultInjector, FaultSpec
 from repro.weather import fields
 from repro.weather import program as wprog
 from repro.weather.program import StencilProgram
+from repro.launch.cache import use_compile_cache
 
 
 def kill_device_demo(args):
     """Mesh-failover drill: persistent device loss mid-flight."""
     import numpy as np
 
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-          if hasattr(jax.sharding, "AxisType") else {})
-    mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     inj = FaultInjector([FaultSpec(kind="device_loss", round=1,
                                    device=args.kill_device, once=False)],
                         seed=0)
@@ -99,6 +99,7 @@ def kill_device_demo(args):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--slots", type=int, default=2,
                     help="ensemble slots per cached plan")

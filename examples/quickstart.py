@@ -20,9 +20,11 @@ from repro.kernels.hdiff import ref as href
 from repro.kernels.hdiff.hdiff import hdiff_pallas
 from repro.kernels.vadvc import ref as vref
 from repro.kernels.vadvc.vadvc import vadvc_pallas
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(0)
     nz, ny, nx = grid = (64, 256, 256)
     print(f"== NERO quickstart on the paper's {nx}x{ny}x{nz} domain ==")

@@ -14,9 +14,11 @@ import numpy as np
 from repro.configs import registry
 from repro.models import api
 from repro.serve.engine import Request, ServeEngine
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-27b",
                     choices=registry.ARCH_IDS)

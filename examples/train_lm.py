@@ -18,6 +18,7 @@ from repro.data import synthetic
 from repro.models import api
 from repro.train import loop, optim
 from repro.launch.mesh import make_mesh
+from repro.launch.cache import use_compile_cache
 
 # ~100M params: 12 layers, d=768 (tinyllama family); param_count() = 129M
 CFG_100M = ModelConfig(
@@ -27,6 +28,7 @@ CFG_100M = ModelConfig(
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

@@ -28,9 +28,11 @@ import numpy as np
 from repro.weather import domain, fields
 from repro.weather.program import DycoreProgram, compile_dycore
 from repro.launch.mesh import make_mesh
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--grid", default="16,64,64")
     ap.add_argument("--steps", type=int, default=10)

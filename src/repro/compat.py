@@ -1,27 +1,14 @@
-"""jax version-compatibility shims (this container ships jax 0.4.x).
-
-Kernel-local Pallas shims live in repro.kernels.compat (CompilerParams);
-this module holds the cross-cutting ones.  Mesh axis_types guarding lives
-in repro.launch.mesh.make_mesh.
-"""
+"""The one shard_map spelling the weather path uses (jax 0.9)."""
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):            # jax >= 0.5
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        """shard_map without replication checking, either jax spelling."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """`jax.shard_map` without replication checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-else:                                    # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        """shard_map without replication checking, either jax spelling."""
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
 
 __all__ = ["shard_map"]

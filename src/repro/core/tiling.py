@@ -40,6 +40,13 @@ class OpSpec:
     `extra_vmem_buffers`: padded-window-sized dtype-width buffers the kernel
     allocates beyond the streamed fields and fp32 scratch (dycore_kstep: 2,
     the double-buffered `w` prefetch slots).
+    `input_windows`: when > 1, each input stream is staged as that many
+    whole tile windows (the aliased prev/cur/next refs: 3) rather than one
+    halo-padded tile.
+    `live_values`: padded-window-sized fp32 values the kernel body keeps
+    live besides its scratch refs (the TPU compiler places them in VMEM
+    too) — read off the compiler's scoped-VMEM reports at the paper's
+    domain, so that the plans the model admits are the plans that compile.
     """
 
     name: str
@@ -53,6 +60,8 @@ class OpSpec:
     halo_tiles: Tuple[int, int, int] = (0, 0, 0)
     scratch_padded: bool = False
     extra_vmem_buffers: float = 0.0
+    input_windows: int = 1
+    live_values: float = 0.0
 
     @property
     def bytes_moved_per_point(self) -> float:
@@ -70,7 +79,8 @@ class OpSpec:
 # reused across neighbors amortize; we count the gridtools fused-op count).
 HDIFF = OpSpec(
     name="hdiff", fields_in=1, fields_out=1, halo=(0, 2, 2),
-    seq_axes=(), parallel_axes=(0, 1, 2), flops_per_point=21.0)
+    seq_axes=(), parallel_axes=(0, 1, 2), flops_per_point=21.0,
+    input_windows=3, live_values=14)
 
 # vadvc: 7 input fields (ccol,dcol,wcon,ustage,upos,utens,utensstage),
 # 1 output; forward+backward sweep ~ 38 flops/point; sequential in z (axis 0
@@ -96,13 +106,15 @@ LRU_SCAN = OpSpec(
 # hdiff.  4 streamed inputs (f, w, utens, utens_stage), 2 outputs (f_new,
 # stage); z stays whole (the solve is sequential) and so does x (the kernel
 # realizes the periodic x-halo as a VMEM lane roll, so only y is tiled and
-# only the 2-deep y-halo is re-read from HBM); 6 tile-shaped fp32 VMEM
-# temporaries (fwork/wwork/rhs/ccol/dcol/stage).
+# only the 2-deep y-halo is re-read from HBM); every input arrives as three
+# aliased windows, 6 padded-window fp32 VMEM temporaries (fwork/wwork/rhs/
+# ccol/dcol/stage), and the hdiff stage keeps ~18 window-sized values live.
 # flops/point = vadvc(38) + update(2) + hdiff(21).
 DYCORE_FUSED = OpSpec(
     name="dycore_fused", fields_in=4, fields_out=2, halo=(0, 2, 0),
     seq_axes=(0, 2), parallel_axes=(1,), flops_per_point=61.0,
-    scratch_fields=6)
+    scratch_fields=6, scratch_padded=True, input_windows=3,
+    live_values=18)
 
 
 # hadv_upwind: first-order donor-cell horizontal advection.  The stencil
@@ -110,7 +122,8 @@ DYCORE_FUSED = OpSpec(
 # asymmetric); the tile model keeps the symmetric one-sided halo convention.
 HADV_UPWIND = OpSpec(
     name="hadv_upwind", fields_in=1, fields_out=1, halo=(0, 1, 1),
-    seq_axes=(), parallel_axes=(0, 1, 2), flops_per_point=5.0)
+    seq_axes=(), parallel_axes=(0, 1, 2), flops_per_point=5.0,
+    input_windows=2, live_values=6)
 
 # vadvc_update: the paper's ablation composition — the vadvc Thomas solve
 # fused with the point-wise leapfrog update (no hdiff).  Same 7 input
@@ -151,18 +164,35 @@ def pipeline_spec(name: str, stage_specs: Sequence[OpSpec], *,
         scratch_fields=max(s.scratch_fields for s in stage_specs))
 
 
-def snap_to_divisor(t: int, n: int, lo: int = 2) -> int:
-    """Largest divisor of `n` that is `<= t` and `>= lo`; falls back to `n`
-    itself when no divisor lands in `[lo, t]`.
+LANES = int(hw.VPU_LANES[1])          # minor-most block dim: 128 lanes
 
-    The ONE snapping rule every kernel package uses to turn an auto-tuned
-    tile extent into a legal one (`kernels/*/ops.py` used to each carry a
-    private halving/decrement loop — they drifted; this is the unified
-    largest-divisor-below semantics of the fused dycore's `snap_ty`)."""
-    t = max(lo, min(int(t), n))
-    while n % t and t > lo:
-        t -= 1
-    return t if n % t == 0 else n
+# The scoped-VMEM limit every `pallas_call` passes to the TPU compiler IS
+# the planner's VMEM budget (`TilePlan.fits`): one number, the spec's
+# usable near-memory capacity.
+VMEM_LIMIT_BYTES = int(hw.VMEM_USABLE)
+
+
+def sublanes(dtype) -> int:
+    """Second-minor block alignment for `dtype`: 8 rows of 32-bit, 16 of
+    16-bit (packed sublanes), 32 of 8-bit."""
+    return int(hw.VPU_LANES[0]) * max(1, 4 // hw.dtype_bytes(dtype))
+
+
+def snap_to_divisor(t: int, n: int, *, align: int, lo: int = 1) -> int:
+    """The ONE tile-snapping rule: the largest divisor of `n` that is a
+    multiple of `align`, `>= lo` and `<= t`; else the smallest such
+    divisor above `t`; else `n` itself (a block equal to the whole extent
+    is always legal).
+
+    `align` is the TPU block rule for the dimension: `sublanes(dtype)` for
+    the second-minor one, `LANES` for the minor one — Mosaic refuses any
+    other block extent, and the interpreter would not notice."""
+    first = -(-max(lo, 1) // align) * align
+    legal = [d for d in range(first, n + 1, align) if n % d == 0]
+    below = [d for d in legal if d <= t]
+    if below:
+        return below[-1]
+    return legal[0] if legal else n
 
 
 def dycore_whole_state_spec(n_fields: int = 4) -> OpSpec:
@@ -173,19 +203,18 @@ def dycore_whole_state_spec(n_fields: int = 4) -> OpSpec:
     plus the shared `w` slab amortized over the field axis — `fields_in =
     3 + 1/n_fields` (the planner's byte accounting tolerates a fractional
     stream).  VMEM is a different story: `w` amortizes in *traffic* but
-    stays fully resident next to the per-field windows while the innermost
-    field iterations reuse it, so it is counted as a 7th tile-shaped
-    scratch buffer (6 pipeline temporaries + the resident shared-`w`
-    window).  That is why the whole-state space is registered separately —
-    its VMEM pressure, and hence the legal-tile set, depends on the field
-    count.
+    its three aliased windows stay double-buffered next to the per-field
+    ones (the VMEM model rounds `fields_in` up to whole streams).  That is
+    why the whole-state space is registered separately — its traffic, and
+    hence the Pareto pick, depends on the field count.
     """
     if n_fields < 1:
         raise ValueError(f"n_fields={n_fields} must be >= 1")
     return OpSpec(
         name="dycore_whole_state", fields_in=3 + 1.0 / n_fields,
         fields_out=2, halo=(0, 2, 0), seq_axes=(0, 2), parallel_axes=(1,),
-        flops_per_point=61.0, scratch_fields=7)
+        flops_per_point=61.0, scratch_fields=6, scratch_padded=True,
+        input_windows=3, live_values=18)
 
 
 DYCORE_WHOLE_STATE = dycore_whole_state_spec()
@@ -220,7 +249,8 @@ def dycore_kstep_spec(n_fields: int = 4, k_steps: int = 2) -> OpSpec:
         name="dycore_kstep", fields_in=3 + 1.0 / n_fields, fields_out=2,
         halo=(0, 0, 0), halo_tiles=(0, 1, 0), seq_axes=(0, 2),
         parallel_axes=(1,), flops_per_point=61.0 * k_steps,
-        scratch_fields=8, scratch_padded=True, extra_vmem_buffers=2.0)
+        scratch_fields=8, scratch_padded=True, extra_vmem_buffers=2.0,
+        live_values=14)
 
 
 DYCORE_KSTEP = dycore_kstep_spec()
@@ -257,15 +287,23 @@ class TilePlan:
     @property
     def vmem_bytes(self) -> int:
         """NERO's "resource utilization" axis: bytes of near-memory the plan
-        claims, with pipeline double-buffering on the streamed fields, the
-        op's explicit extra buffers (e.g. the k-step kernel's double-buffered
-        `w` prefetch slots), and padded-window scratch where the op keeps
-        whole working windows as temporaries."""
+        claims — pipeline double-buffering on the streamed fields (each input as
+        `input_windows` whole windows when the kernel aliases its halo
+        windows), the op's explicit extra buffers (e.g. the k-step kernel's
+        double-buffered `w` prefetch slots), padded-window scratch where
+        the op keeps whole working windows as temporaries, and the values
+        its body keeps live.  `TilePlan.fits` holds this against the same
+        `VMEM_LIMIT_BYTES` every kernel passes to the compiler."""
         b = hw.dtype_bytes(self.dtype)
         pt = math.prod(self.padded_tile)
-        streamed = (self.op.fields_in + self.op.fields_out) * pt * b
-        scratch_pts = pt if self.op.scratch_padded else self.tile_points
-        scratch = self.op.scratch_fields * scratch_pts * max(b, 4)
+        tp = self.tile_points
+        win = self.op.input_windows
+        in_pts = win * tp if win > 1 else pt
+        streamed = (math.ceil(self.op.fields_in) * in_pts
+                    + self.op.fields_out * pt) * b
+        scratch_pts = pt if self.op.scratch_padded else tp
+        scratch = ((self.op.scratch_fields + self.op.live_values)
+                   * scratch_pts * max(b, 4))
         extra = self.op.extra_vmem_buffers * pt * b
         return int(streamed * self.pipeline_depth + scratch + extra)
 
@@ -278,7 +316,7 @@ class TilePlan:
         """Minor-most dim multiple of 128 lanes, next of 8 sublanes — the MXU
         /VPU alignment the paper's BRAM-width matching corresponds to."""
         z, y, x = self.padded_tile
-        return (x % hw.VPU_LANES[1] == 0) and (y % hw.VPU_LANES[0] == 0)
+        return (x % LANES == 0) and (y % sublanes(self.dtype) == 0)
 
     # -- traffic ------------------------------------------------------------
     @property

@@ -7,8 +7,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _copy_kernel(in_ref, out_ref):
     out_ref[...] = in_ref[...]
@@ -28,7 +26,7 @@ def copy_pallas(src: jnp.ndarray, tr: int = 256,
         in_specs=[spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="nero_copy",

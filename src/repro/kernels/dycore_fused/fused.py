@@ -40,8 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
+from repro.core.tiling import VMEM_LIMIT_BYTES
 from repro.kernels.hdiff.ref import DEFAULT_COEFF
 from repro.kernels.vadvc.ref import BET_M, BET_P, DTR_STAGE
 
@@ -216,8 +215,9 @@ def fused_dycore_pallas(f: jnp.ndarray, w: jnp.ndarray, utens: jnp.ndarray,
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct(bshape, f.dtype)] * 2,
         scratch_shapes=[scratch] * 6,   # fwork, wwork, rhs, ccol, dcol, stage
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_dycore_fused",
     )
@@ -308,8 +308,9 @@ def fused_dycore_whole_state_pallas(fs: jnp.ndarray, w: jnp.ndarray,
         out_specs=[lay.out_spec, lay.out_spec],
         out_shape=[jax.ShapeDtypeStruct(lay.fshape, fs.dtype)] * 2,
         scratch_shapes=[scratch] * 6,   # fwork, wwork, rhs, ccol, dcol, stage
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_dycore_whole_state",
     )
@@ -479,7 +480,7 @@ def fused_dycore_kstep_pallas(fs: jnp.ndarray, w: jnp.ndarray,
         kernel = functools.partial(_kstep_kernel_prefetch, nz=nz, ty=ty,
                                    k_steps=k_steps, dt=dt, coeff=coeff,
                                    nyb=lay.nyb)
-        wspec = [pl.BlockSpec(memory_space=pltpu.ANY)]
+        wspec = [pl.BlockSpec(memory_space=pl.ANY)]
         scratch = scratch + [pltpu.VMEM((2, nz, 3 * ty, nx), w.dtype),
                              pltpu.SemaphoreType.DMA((2, 3))]
     else:
@@ -494,8 +495,9 @@ def fused_dycore_kstep_pallas(fs: jnp.ndarray, w: jnp.ndarray,
         out_specs=[lay.out_spec, lay.out_spec],
         out_shape=[jax.ShapeDtypeStruct(lay.fshape, fs.dtype)] * 2,
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_dycore_kstep",
     )

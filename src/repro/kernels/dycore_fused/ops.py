@@ -47,31 +47,31 @@ def _auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def snap_ty(ty: int, ny: int) -> int:
-    """Largest legal y-window <= `ty`: a divisor of ny, >= 2 (falling back to
-    a single whole-y window when ny has no divisor in [2, ty])."""
-    return tiling.snap_to_divisor(ty, ny, lo=2)
+def snap_ty(ty: int, ny: int, dtype=jnp.float32) -> int:
+    """Legal y-window near `ty`: a sublane-aligned divisor of ny, or the
+    whole extent (`tiling.snap_to_divisor`)."""
+    return tiling.snap_to_divisor(ty, ny, align=tiling.sublanes(dtype),
+                                  lo=2)
 
 
 def plan_tile(grid_shape, dtype) -> int:
     """Auto-tuned y-window for the fused kernel (paper Fig. 6 stage)."""
     tuned = autotune.tune_named("dycore_fused", grid_shape, dtype)
-    return snap_ty(tuned.plan.tile[1], grid_shape[1])
+    return snap_ty(tuned.plan.tile[1], grid_shape[1], dtype)
 
 
-def snap_ty_kstep(ty: int, ny: int, k_steps: int) -> int:
-    """Legal k-step y-window: a divisor of `ny` that is at least
-    `k_steps * HALO` (each local step consumes a HALO-deep ring of window
-    validity).  Prefers the largest legal divisor <= `ty`; falls back to the
-    smallest legal divisor (possibly ny itself) when `ty` is too small."""
+def snap_ty_kstep(ty: int, ny: int, k_steps: int, dtype=jnp.float32) -> int:
+    """Legal k-step y-window: a sublane-aligned divisor of `ny` (or ny
+    itself) that is at least `k_steps * HALO` (each local step consumes a
+    HALO-deep ring of window validity) — `tiling.snap_to_divisor` with
+    that floor."""
     lo = max(2, k_steps * HALO)
     if ny < lo:
         raise ValueError(
             f"ny={ny} < k_steps*HALO={lo}: no window can hold the k-step "
             f"validity front; use a bigger grid or a smaller k_steps")
-    divisors = [d for d in range(lo, ny + 1) if ny % d == 0]
-    at_most = [d for d in divisors if d <= ty]
-    return at_most[-1] if at_most else divisors[0]
+    return tiling.snap_to_divisor(ty, ny, align=tiling.sublanes(dtype),
+                                  lo=lo)
 
 
 def plan_tile_kstep(grid_shape, dtype, n_fields: int, k_steps: int,
@@ -89,7 +89,7 @@ def plan_tile_kstep(grid_shape, dtype, n_fields: int, k_steps: int,
     hier = hier or hw.tpu_v5e()
     spec = tiling.dycore_kstep_spec(n_fields, k_steps)
     tuned = autotune.tune(spec, grid_shape, dtype, hier=hier)
-    ty = snap_ty_kstep(tuned.plan.tile[1], grid_shape[1], k_steps)
+    ty = snap_ty_kstep(tuned.plan.tile[1], grid_shape[1], k_steps, dtype)
     plan = tiling.TilePlan(op=spec, grid_shape=tuple(grid_shape),
                            tile=(grid_shape[0], ty, grid_shape[2]),
                            dtype=str(jnp.dtype(dtype)))
@@ -135,7 +135,7 @@ def plan_tile_whole_state(grid_shape, dtype, n_fields: int) -> int:
     """
     spec = tiling.dycore_whole_state_spec(n_fields)
     tuned = autotune.tune(spec, grid_shape, dtype)
-    return snap_ty(tuned.plan.tile[1], grid_shape[1])
+    return snap_ty(tuned.plan.tile[1], grid_shape[1], dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("coeff", "dt", "use_pallas",
@@ -154,7 +154,7 @@ def fused_step(f: jnp.ndarray, wcon: jnp.ndarray, utens: jnp.ndarray,
     if interpret is None:
         interpret = _auto_interpret()
     ny = f.shape[-2]
-    ty = snap_ty(ty, ny) if ty else plan_tile(f.shape[-3:], f.dtype)
+    ty = snap_ty(ty, ny, f.dtype) if ty else plan_tile(f.shape[-3:], f.dtype)
     w = wcon + jnp.roll(wcon, -1, axis=-1)   # wcon_i + wcon_{i+1}, periodic
     return fused_dycore_pallas(f, w, utens, utens_stage, coeff=coeff, dt=dt,
                                ty=ty, interpret=interpret)
@@ -179,7 +179,7 @@ def fused_step_whole_state(fs: jnp.ndarray, wcon: jnp.ndarray,
     if interpret is None:
         interpret = _auto_interpret()
     nf, _, ny, _ = fs.shape[-4:]
-    ty = (snap_ty(ty, ny) if ty
+    ty = (snap_ty(ty, ny, fs.dtype) if ty
           else plan_tile_whole_state(fs.shape[-3:], fs.dtype, nf))
     w = wcon + jnp.roll(wcon, -1, axis=-1)   # wcon_i + wcon_{i+1}, periodic
     return fused_dycore_whole_state_pallas(fs, w, utens, utens_stage,
@@ -206,7 +206,7 @@ def fused_step_kstep(fs: jnp.ndarray, wcon: jnp.ndarray,
     if interpret is None:
         interpret = _auto_interpret()
     nf, _, ny, _ = fs.shape[-4:]
-    ty = (snap_ty_kstep(ty, ny, k_steps) if ty
+    ty = (snap_ty_kstep(ty, ny, k_steps, fs.dtype) if ty
           else plan_tile_kstep(fs.shape[-3:], fs.dtype, nf, k_steps))
     w = wcon + jnp.roll(wcon, -1, axis=-1)   # wcon_i + wcon_{i+1}, periodic
     return fused_dycore_kstep_pallas(fs, w, utens, utens_stage,

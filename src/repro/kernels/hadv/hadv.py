@@ -14,9 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
+from repro.core.tiling import VMEM_LIMIT_BYTES
 from repro.kernels.hadv.ref import DEFAULT_CFL
 
 
@@ -40,8 +40,9 @@ def _hadv_kernel(prev_ref, cur_ref, out_ref, *, cfl: float,
     row_ids = j * ty + jax.lax.broadcasted_iota(jnp.int32, (ty, 1), 0)
     valid = row_ids >= 1
     center = work[1: 1 + ty, :]
-    res = center.at[:, 1:].set(jnp.where(valid, interior, center[:, 1:]))
-    out_ref[0] = res.astype(out_ref.dtype)
+    out_ref[0] = center.astype(out_ref.dtype)
+    out_ref[0, :, 1:] = jnp.where(valid, interior,
+                                  center[:, 1:]).astype(out_ref.dtype)
 
 
 def hadv_pallas(src: jnp.ndarray, cfl: float = DEFAULT_CFL,
@@ -66,8 +67,9 @@ def hadv_pallas(src: jnp.ndarray, cfl: float = DEFAULT_CFL,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_hadv_upwind",
     )

@@ -15,9 +15,11 @@ HALO = 1   # one-sided (low-side) reach in y and x
 
 
 def plan_tile(grid_shape, dtype) -> int:
-    """Auto-tuned y-window for the Pallas kernel, snapped to a divisor."""
+    """Auto-tuned y-window for the Pallas kernel, snapped to a
+    sublane-aligned divisor (or the whole extent)."""
     tuned = autotune.tune_named("hadv_upwind", grid_shape, dtype)
-    return tiling.snap_to_divisor(tuned.plan.tile[1], grid_shape[1], lo=1)
+    return tiling.snap_to_divisor(tuned.plan.tile[1], grid_shape[1],
+                                  align=tiling.sublanes(dtype))
 
 
 def resolve_tile(grid_shape, dtype) -> tiling.TilePlan:

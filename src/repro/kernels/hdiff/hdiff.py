@@ -26,8 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
+from repro.core.tiling import VMEM_LIMIT_BYTES
 from repro.kernels.hdiff.ref import DEFAULT_COEFF
 
 
@@ -71,20 +70,22 @@ def _hdiff_kernel(prev_ref, cur_ref, next_ref, out_ref, *, coeff: float,
     row_ids = j * ty + jax.lax.broadcasted_iota(jnp.int32, (ty, 1), 0)
     valid = (row_ids >= 2) & (row_ids < ny - 2)
     center = work[2: 2 + ty, :]
-    res = center.at[:, 2: nx - 2].set(
-        jnp.where(valid, interior, center[:, 2: nx - 2]))
-    out_ref[0] = res.astype(out_ref.dtype)
+    out_ref[0] = center.astype(out_ref.dtype)
+    out_ref[0, :, 2: nx - 2] = jnp.where(
+        valid, interior, center[:, 2: nx - 2]).astype(out_ref.dtype)
 
 
-def _hdiff_kstep_kernel(prev_ref, cur_ref, next_ref, out_ref, *,
+def _hdiff_kstep_kernel(prev_ref, cur_ref, next_ref, out_ref, w_ref, *,
                         coeff: float, ny: int, ty: int, k_steps: int):
     j = pl.program_id(1)
     nx = cur_ref.shape[2]
     out_dtype = out_ref.dtype
     h = 3 * ty   # slab height: prev + cur + next windows
 
-    slab = jnp.concatenate([prev_ref[0], cur_ref[0], next_ref[0]],
-                           axis=0).astype(jnp.float32)       # (3*ty, nx)
+    # The (3*ty, nx) fp32 slab lives in VMEM scratch; each step rewrites
+    # its interior in place.
+    w_ref[...] = jnp.concatenate([prev_ref[0], cur_ref[0], next_ref[0]],
+                                 axis=0).astype(jnp.float32)
     # Global row id of every slab row *as if* the neighbor windows were
     # not edge-clamped.  Clamp duplicates then get out-of-range ids, so
     # they are never recomputed, and the global passthrough ring (rows
@@ -94,7 +95,9 @@ def _hdiff_kstep_kernel(prev_ref, cur_ref, next_ref, out_ref, *,
                + jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0))
     valid = (row_ids >= 2) & (row_ids < ny - 2)
 
-    def step(_, w):
+    def step(_, carry):
+        w = w_ref[...]
+
         def s(dj: int, di: int) -> jnp.ndarray:
             return w[2 + dj: h - 2 + dj, 2 + di: nx - 2 + di]
 
@@ -115,14 +118,16 @@ def _hdiff_kstep_kernel(prev_ref, cur_ref, next_ref, out_ref, *,
         fly_m = jnp.where(fly_m * (s(0, 0) - s(-1, 0)) > 0.0, 0.0, fly_m)
         interior = s(0, 0) - coeff * ((flx - flx_m) + (fly - fly_m))
 
-        w = w.at[2: h - 2, 2: nx - 2].set(
-            jnp.where(valid[2: h - 2], interior, w[2: h - 2, 2: nx - 2]))
+        upd = jnp.where(valid[2: h - 2], interior, s(0, 0))
         # Round-trip through the storage dtype so each in-kernel step
-        # rounds exactly like a separate launch (bit-equal ragged tails).
-        return w.astype(out_dtype).astype(jnp.float32)
+        # rounds exactly like a separate launch (bit-equal ragged tails);
+        # the untouched ring already holds storage-dtype values.
+        w_ref[2: h - 2, 2: nx - 2] = upd.astype(out_dtype).astype(
+            jnp.float32)
+        return carry
 
-    slab = jax.lax.fori_loop(0, k_steps, step, slab)
-    out_ref[0] = slab[ty: 2 * ty].astype(out_dtype)
+    jax.lax.fori_loop(0, k_steps, step, 0)
+    out_ref[0] = w_ref[ty: 2 * ty].astype(out_dtype)
 
 
 def hdiff_kstep_pallas(src: jnp.ndarray, coeff: float = DEFAULT_COEFF,
@@ -160,8 +165,10 @@ def hdiff_kstep_pallas(src: jnp.ndarray, coeff: float = DEFAULT_COEFF,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((3 * ty, nx), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_hdiff_kstep",
     )
@@ -191,8 +198,9 @@ def hdiff_pallas(src: jnp.ndarray, coeff: float = DEFAULT_COEFF,
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_hdiff",
     )

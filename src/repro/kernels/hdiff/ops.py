@@ -21,14 +21,12 @@ HALO = 2   # the compound stencil's one-sided reach in y and x
 
 
 def plan_tile(grid_shape, dtype) -> int:
-    """Auto-tuned y-window for the Pallas kernel (paper Fig. 6 stage).
-
-    Snapping goes through `tiling.snap_to_divisor` — the same
-    largest-divisor-below rule as the fused dycore's `snap_ty` (this
-    module used to halve instead, which drifted from the unified
-    `resolve_tile` path for tuned sizes like 24 on ny=32)."""
+    """Auto-tuned y-window for the Pallas kernel (paper Fig. 6 stage),
+    snapped by `tiling.snap_to_divisor` to a sublane-aligned divisor of
+    the y extent (or the whole extent)."""
     tuned = autotune.tune_named("hdiff", grid_shape, dtype)
-    return tiling.snap_to_divisor(tuned.plan.tile[1], grid_shape[1], lo=2)
+    return tiling.snap_to_divisor(tuned.plan.tile[1], grid_shape[1],
+                                  align=tiling.sublanes(dtype), lo=2)
 
 
 def resolve_tile(grid_shape, dtype) -> tiling.TilePlan:

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _lru_kernel(a_ref, b_ref, out_ref, h_ref, *, tt: int):
     t_idx = pl.program_id(1)
@@ -52,7 +50,7 @@ def lru_scan_pallas(a: jnp.ndarray, b: jnp.ndarray, tt: int = 32,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((1, tc), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="nero_lru_scan",

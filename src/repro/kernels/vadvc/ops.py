@@ -13,17 +13,14 @@ from repro.kernels.vadvc.vadvc import vadvc_pallas
 
 
 def plan_tile(grid_shape, dtype):
-    """Auto-tuned (tj, ti) horizontal window (paper's 64x2 fp32 analogue).
-
-    Snapping goes through `tiling.snap_to_divisor` (largest divisor below
-    the tuned extent) — the same rule as every other kernel package; the
-    old private power-of-two halving drifted from the unified
-    `resolve_tile` path on non-power-of-two extents."""
+    """Auto-tuned (tj, ti) horizontal window (paper's 64x2 fp32 analogue),
+    snapped by `tiling.snap_to_divisor`: tj to sublane-aligned divisors of
+    y, ti to 128-lane divisors of x (or the whole extents)."""
     tuned = autotune.tune_named("vadvc", grid_shape, dtype)
     _, tj, ti = tuned.plan.tile
     nz, ny, nx = grid_shape
-    return (tiling.snap_to_divisor(tj, ny, lo=1),
-            tiling.snap_to_divisor(ti, nx, lo=1))
+    return (tiling.snap_to_divisor(tj, ny, align=tiling.sublanes(dtype)),
+            tiling.snap_to_divisor(ti, nx, align=tiling.LANES))
 
 
 def resolve_tile(grid_shape, dtype) -> tiling.TilePlan:

@@ -138,30 +138,26 @@ def vadvc(u_stage: jnp.ndarray, wcon: jnp.ndarray, u_pos: jnp.ndarray,
                                       utens_stage))
     a, b, c, d = _system(u_stage, wcon, u_pos, utens, utens_stage, jnp)
 
-    # Thomas forward elimination.
+    # Thomas forward elimination (a[0] == 0, so a zero carry starts it),
+    # then back substitution as a reverse scan (c[-1] == 0 likewise).  The
+    # equivalent form that scanned `[::-1]`-reversed slices and
+    # concatenated the first level back on came out wrong on the TPU for
+    # the lowest 28 of 64 levels.
     def fwd(carry, xs):
         cprev, dprev = carry
         a_k, b_k, c_k, d_k = xs
         denom = 1.0 / (b_k - cprev * a_k)
-        c_new = c_k * denom
-        d_new = (d_k - dprev * a_k) * denom
-        return (c_new, d_new), (c_new, d_new)
+        out = (c_k * denom, (d_k - dprev * a_k) * denom)
+        return out, out
 
-    c0 = c[0] / b[0]
-    d0 = d[0] / b[0]
-    _, (cs_, ds_) = jax.lax.scan(fwd, (c0, d0), (a[1:], b[1:], c[1:], d[1:]))
-    cp = jnp.concatenate([c0[None], cs_], axis=0)
-    dp = jnp.concatenate([d0[None], ds_], axis=0)
-
-    # Back substitution.
-    def bwd(carry, xs):
+    def bwd(x_next, xs):
         c_k, d_k = xs
-        x = d_k - c_k * carry
+        x = d_k - c_k * x_next
         return x, x
 
-    xlast = dp[-1]
-    _, xs_rev = jax.lax.scan(bwd, xlast, (cp[:-1][::-1], dp[:-1][::-1]))
-    x = jnp.concatenate([xs_rev[::-1], xlast[None]], axis=0)
+    zero = jnp.zeros_like(a[0])
+    _, cd = jax.lax.scan(fwd, (zero, zero), (a, b, c, d))
+    _, x = jax.lax.scan(bwd, zero, cd, reverse=True)
     out = DTR_STAGE * (x - u_pos)
     return out.astype(in_dtype)
 

@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
+from repro.core.tiling import VMEM_LIMIT_BYTES
 from repro.kernels.vadvc.ref import BET_M, BET_P, DTR_STAGE
 
 
@@ -131,8 +130,9 @@ def vadvc_pallas(u_stage: jnp.ndarray, wcon: jnp.ndarray, u_pos: jnp.ndarray,
             pltpu.VMEM((nz, tj, ti), jnp.float32),   # ccol
             pltpu.VMEM((nz, tj, ti), jnp.float32),   # dcol
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="nero_vadvc",
     )

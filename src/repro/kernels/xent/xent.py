@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -102,7 +101,7 @@ def xent_pallas(hidden: jnp.ndarray, head: jnp.ndarray,
             pltpu.VMEM((bn, 1), jnp.float32),            # running sum
             pltpu.VMEM((bn, 1), jnp.float32),            # gold logit
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="nero_fused_xent",

@@ -28,10 +28,8 @@ def make_mesh(shape, axes) -> Mesh:
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax (dryrun.py does this)")
     arr = np.asarray(devs[:n]).reshape(shape)
-    if hasattr(jax.sharding, "AxisType"):   # jax >= 0.5
-        return Mesh(arr, tuple(axes),
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return Mesh(arr, tuple(axes))
+    return Mesh(arr, tuple(axes),
+                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

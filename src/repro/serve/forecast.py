@@ -622,8 +622,11 @@ class ForecastEngine:
                     delay *= 2
         # Retries exhausted: degrade to the interpreter lowering once —
         # unless the failure names a lost device (degradation cannot
-        # resurrect hardware; that case belongs to mesh failover).
-        if not plan.interpret and getattr(last, "lost_device", None) is None:
+        # resurrect hardware; that case belongs to mesh failover), or the
+        # backend is a TPU (a round served by the interpreter would hide
+        # the device's failure behind an "ok").
+        if (not plan.interpret and not _wprog.on_tpu()
+                and getattr(last, "lost_device", None) is None):
             try:
                 ax_e, ax_y, ax_x = self.mesh_axes
                 plan2 = _wprog.compile(lane.key, mesh=self.mesh, ax_e=ax_e,

@@ -207,10 +207,9 @@ def gather_state(state: WeatherState) -> WeatherState:
 
 
 def _mesh_from(devices, shape: Tuple[int, int], axes) -> Mesh:
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * len(axes)}
-          if hasattr(jax.sharding, "AxisType") else {})
     n = shape[0] * shape[1]
-    return Mesh(np.asarray(devices[:n]).reshape(shape), tuple(axes), **kw)
+    return Mesh(np.asarray(devices[:n]).reshape(shape), tuple(axes),
+                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def failover_meshes(devices, grids: Iterable[Tuple[int, int, int]],

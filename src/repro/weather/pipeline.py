@@ -221,7 +221,8 @@ def _pipeline_resolve_tile(spec: tiling.OpSpec):
         grid = tuple(int(g) for g in compute_grid)
         tuned = autotune.tune(spec, grid, dtype)
         tz, ty, tx = tuned.plan.tile
-        ty = tiling.snap_to_divisor(ty, grid[1], lo=1)
+        ty = tiling.snap_to_divisor(ty, grid[1],
+                                    align=tiling.sublanes(dtype))
         return tiling.TilePlan(op=spec, grid_shape=grid, tile=(tz, ty, tx),
                                dtype=str(jnp.dtype(dtype)))
     return resolve
@@ -232,7 +233,9 @@ def _pipeline_traffic(spec: tiling.OpSpec, stages):
         prog = plan.program
         nz, ny, nx = prog.grid_shape
         tile = (nz if 0 in spec.seq_axes else 1,
-                tiling.snap_to_divisor(model_ty, ny, lo=1), nx)
+                tiling.snap_to_divisor(model_ty, ny,
+                                       align=tiling.sublanes(prog.dtype)),
+                nx)
         pairs = [(_stage_tile_spec(st),
                   len(st.fields) if st.fields is not None
                   else prog.n_fields) for st in stages]

@@ -50,7 +50,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import shard_map as _shard_map
 from repro.core import autotune, hwspec, memmodel, perfmodel, tiling
-from repro.kernels.dycore_fused import ops as fused_ops
 from repro.weather import stencil_ops as _sops
 from repro.weather.fields import PROGNOSTIC, WeatherState, zeros_state
 from repro.weather.stencil_ops import (StencilOpDef, get_stencil_op,
@@ -725,7 +724,7 @@ def compile(program: StencilProgram, mesh: Optional[Mesh] = None, *,
     nf = program.n_fields
     halo = opdef.halo
     if interpret is None:
-        interpret = fused_ops._auto_interpret()
+        interpret = not on_tpu()
 
     if mesh is not None:
         for ax in (ax_y, ax_x):
@@ -835,13 +834,18 @@ def compile(program: StencilProgram, mesh: Optional[Mesh] = None, *,
         if collectives is None:
             collectives = opdef.generic_collectives(py, px, k)
 
-    resolved_prefetch = (not interpret) if prefetch_w is None else prefetch_w
+    if prefetch_w is None:
+        # The `w` prefetch DMAs whole rows; Mosaic slices them only
+        # lane-aligned.  Distributed slabs are padded to whole lanes; a
+        # single-chip periodic domain is not, so it needs nx % 128 == 0.
+        prefetch_w = not interpret and (mesh is not None
+                                        or nx % tiling.LANES == 0)
 
     plan = ExecutionPlan(
         program=program, variant=variant, k_steps=k, tile_ty=ty,
         tile_plan=tile_plan, local_grid=(nz, ly, lx),
         compute_grid=compute_grid, rides=rides, interpret=interpret,
-        prefetch_w=resolved_prefetch, exchange=exchange,
+        prefetch_w=prefetch_w, exchange=exchange,
         pallas_calls_per_round=pallas_calls,
         collectives_per_round=collectives, mesh=mesh,
         mesh_axes=(ax_e, ax_y, ax_x))
@@ -904,9 +908,13 @@ def _measure_tile_candidates(plan: ExecutionPlan, program: StencilProgram,
     # actually time (each costs a compile + a few steps).
     cands = tiling.candidate_tiles(base.op, base.grid_shape, program.dtype,
                                    plan.hardware_spec().hierarchy())
+    ny = base.grid_shape[1]
+    align = tiling.sublanes(program.dtype)
     ty_pool = sorted({p.tile[1] for p in cands
                       if p.tile[0] == base.tile[0]
-                      and p.tile[2] == base.tile[2]})
+                      and p.tile[2] == base.tile[2]
+                      and tiling.snap_to_divisor(p.tile[1], ny,
+                                                 align=align) == p.tile[1]})
     if len(ty_pool) > max_measured:
         stride = len(ty_pool) / max_measured
         ty_pool = sorted({ty_pool[int(i * stride)]
@@ -962,6 +970,12 @@ def reference_program(program: StencilProgram) -> StencilProgram:
                                exchange_dtype=None)
 
 
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: Pallas runs natively and
+    no path may fall back to the interpreter."""
+    return jax.default_backend() == "tpu"
+
+
 def compile_with_fallback(program: StencilProgram,
                           mesh: Optional[Mesh] = None, *,
                           ax_e: Optional[str] = "pod", ax_y: str = "data",
@@ -977,10 +991,12 @@ def compile_with_fallback(program: StencilProgram,
       2. ``interpret`` — the SAME plan forced through the Pallas
          interpreter (survives backend codegen/lowering failures; on a
          backend where auto-interpret already resolves True this is the
-         identical plan, so results stay bit-identical),
+         identical plan, so results stay bit-identical).  Skipped on a
+         TPU: there a kernel the chip refuses is a defect to surface, not
+         one to hide behind the interpreter,
       3. ``reference`` — `reference_program(program)`: the op's unfused
          oracle lowering, one step per round (availability over
-         bit-identity — the last resort).
+         bit-identity — the last resort; callers count it).
 
     Returns ``(plan, fallback, errors)``: `fallback` is None when the
     native attempt won, else the winning stage name; `errors` lists
@@ -988,11 +1004,11 @@ def compile_with_fallback(program: StencilProgram,
     error only if every stage fails.  `attempt_hook(program, stage)` is
     the fault-injection seam — `testing.faults.FaultInjector.on_compile`
     plugs in here to rehearse lowering failures deterministically."""
-    attempts = [
-        ("native", program, {"interpret": interpret}),
-        ("interpret", program, {"interpret": True}),
-        ("reference", reference_program(program), {"interpret": True}),
-    ]
+    attempts = [("native", program, {"interpret": interpret})]
+    if not on_tpu():
+        attempts.append(("interpret", program, {"interpret": True}))
+    attempts.append(("reference", reference_program(program),
+                     {"interpret": interpret}))
     errors: list = []
     for stage, prog, kw in attempts:
         try:

@@ -390,6 +390,13 @@ def _dycore_shard_local(plan):
         stacked, wconp = _domain._exchange_packed(
             [(stacked, hx), (wconp, sched.wcon_depth_x)], ax_x, px, dim=-1,
             wire_dtype=sched.wire_dtype)
+        # Whole 128-lane tiles: the k-step kernel's `w` prefetch DMAs
+        # whole rows, which Mosaic only slices lane-aligned.  The extra
+        # high-x columns are edge copies the crop below never reads.
+        xw = -(-stacked.shape[-1] // tiling.LANES) * tiling.LANES
+        lane = lambda a, w: jnp.pad(
+            a, [(0, 0)] * (a.ndim - 1) + [(0, w - a.shape[-1])], mode="edge")
+        stacked, wconp = lane(stacked, xw), lane(wconp, xw + 1)
         fs, ts, ss = (stacked[:, :nf], stacked[:, nf:2 * nf],
                       stacked[:, 2 * nf:])
         # Staggered velocity on the padded slab — valid everywhere: the
@@ -491,16 +498,15 @@ def _hdiff_resolve_tile(variant, compute_grid, dtype, n_fields, ensemble,
     return hdiff_ops.resolve_tile(compute_grid, dtype)
 
 
-def _hdiff_kstep_ty(Y: int, ty: int, k: int) -> int:
-    """The in-kernel k-step window: the divisor of the slab height `Y`
-    closest to the tuned `ty` with at least `max(2, 2k)` rows — each
+def _hdiff_kstep_ty(Y: int, ty: int, k: int, dtype) -> int:
+    """The in-kernel k-step window: a legal window of the slab height `Y`
+    (`tiling.snap_to_divisor`) with at least `max(2, 2k)` rows — each
     in-slab step shrinks the window's valid interior by 2 rows per side,
     so smaller windows would self-corrupt before the round ends.  `Y` is
     always a legal fallback (the deep-ride compile check keeps
     `Y = ly + 4k > 2k`)."""
-    lo = max(2, 2 * k)
-    cands = [d for d in range(lo, Y + 1) if Y % d == 0]
-    return min(cands, key=lambda d: (abs(d - ty), d))
+    return tiling.snap_to_divisor(ty, Y, align=tiling.sublanes(dtype),
+                                  lo=max(2, 2 * k))
 
 
 def _hdiff_kstep_vmem_check(program, shards):
@@ -565,7 +571,7 @@ def _hdiff_shard_local(plan):
             # contract.  Bit-equal to k sequential launches: every step
             # round-trips through the storage dtype in-kernel.
             out = hdiff_kstep_pallas(fs.reshape(-1, Y, X), coeff=coeff,
-                                     ty=_hdiff_kstep_ty(Y, ty, k),
+                                     ty=_hdiff_kstep_ty(Y, ty, k, fs.dtype),
                                      k_steps=k, interpret=interp)
             fs = out.reshape(fs.shape)
         out = fs[..., hy_lo:hy_lo + ly, hx_lo:hx_lo + lx]
@@ -580,7 +586,9 @@ def _hdiff_traffic(plan, model_ty):
     # model_ty may have been resolved on a padded/folded grid (distributed
     # or unfused plans); the traffic model runs on the physical grid, so
     # snap to a legal window of it.
-    tile = (1, tiling.snap_to_divisor(model_ty, ny, lo=1), nx)
+    tile = (1, tiling.snap_to_divisor(model_ty, ny,
+                                      align=tiling.sublanes(prog.dtype)),
+            nx)
     return memmodel.stencil_op_traffic(
         autotune.get_op("hdiff"), prog.grid_shape, prog.dtype,
         n_fields=prog.n_fields, tile=tile, k_steps=plan.k_steps)
@@ -638,20 +646,25 @@ def _vadvc_launch_whole_state(fs, wconp, ts, ss, tile, interp):
     field fold.  Returns the stage-tendency stack.  Shared by the solo
     whole-state lowering and the `vadvc`/`vadvc_update` pipeline stages;
     `tile` extents are re-snapped to the actual fold (the Thomas sweep is
-    bitwise tile-invariant, so snapping never changes results)."""
+    bitwise tile-invariant, so snapping never changes results).  The fold
+    is padded to whole sublane tiles so that a legal row window always
+    exists (the columns are independent; the padding rows are dropped)."""
     e, nf, nz, ly, lx = fs.shape
+    rows = e * nf * ly
+    sub = tiling.sublanes(fs.dtype)
+    padded = -(-rows // sub) * sub
     _, tj, ti = tile
-    ti = tiling.snap_to_divisor(ti, lx, lo=1)
-    tj = tiling.snap_to_divisor(tj, e * nf * ly, lo=1)
+    ti = tiling.snap_to_divisor(ti, lx, align=tiling.LANES)
+    tj = tiling.snap_to_divisor(tj, padded, align=sub)
 
-    def foldf(a):            # (e, nf, nz, ly, lx') -> (nz, e*nf*ly, lx')
-        return a.transpose(2, 0, 1, 3, 4).reshape(nz, e * nf * ly,
-                                                  a.shape[-1])
+    def foldf(a):            # (e, nf, nz, ly, lx') -> (nz, padded, lx')
+        a = a.transpose(2, 0, 1, 3, 4).reshape(nz, rows, a.shape[-1])
+        return jnp.pad(a, ((0, 0), (0, padded - rows), (0, 0)), mode="edge")
 
     wrep = jnp.broadcast_to(wconp[:, None], (e, nf) + wconp.shape[1:])
     out = vadvc_pallas(foldf(fs), foldf(wrep), foldf(fs), foldf(ts),
                        foldf(ss), tj=tj, ti=ti, interpret=interp)
-    return out.reshape(nz, e, nf, ly, lx).transpose(1, 2, 0, 3, 4)
+    return out[:, :rows].reshape(nz, e, nf, ly, lx).transpose(1, 2, 0, 3, 4)
 
 
 def _vadvc_shard_local(plan):
@@ -688,9 +701,10 @@ def _vadvc_shard_local(plan):
         # smaller, so re-snap to the shard's actual extents (static at
         # trace time; a no-op when they already divide).
         _, tj, ti = tile
-        ti = tiling.snap_to_divisor(ti, lx, lo=1)
+        ti = tiling.snap_to_divisor(ti, lx, align=tiling.LANES)
         if variant == "per_field":
-            tj_l = tiling.snap_to_divisor(tj, e * ly, lo=1)
+            tj_l = tiling.snap_to_divisor(tj, e * ly,
+                                          align=tiling.sublanes(wcon.dtype))
 
             def fold(a):         # (e, nz, ly, lx') -> (nz, e*ly, lx')
                 return a.transpose(1, 0, 2, 3).reshape(nz, e * ly,
@@ -749,8 +763,9 @@ def _vadvc_traffic(plan, model_ty):
         _, tj, ti = plan.tile_plan.tile
     else:
         tj, ti = model_ty, nx
-    tile = (nz, tiling.snap_to_divisor(tj, ny, lo=1),
-            tiling.snap_to_divisor(ti, nx, lo=1))
+    tile = (nz, tiling.snap_to_divisor(tj, ny,
+                                       align=tiling.sublanes(prog.dtype)),
+            tiling.snap_to_divisor(ti, nx, align=tiling.LANES))
     return memmodel.stencil_op_traffic(
         autotune.get_op("vadvc"), prog.grid_shape, prog.dtype,
         n_fields=prog.n_fields, tile=tile, k_steps=plan.k_steps)
@@ -912,8 +927,9 @@ def _vadvc_update_traffic(plan, model_ty):
         _, tj, ti = plan.tile_plan.tile
     else:
         tj, ti = model_ty, nx
-    tile = (nz, tiling.snap_to_divisor(tj, ny, lo=1),
-            tiling.snap_to_divisor(ti, nx, lo=1))
+    tile = (nz, tiling.snap_to_divisor(tj, ny,
+                                       align=tiling.sublanes(prog.dtype)),
+            tiling.snap_to_divisor(ti, nx, align=tiling.LANES))
     return memmodel.stencil_op_traffic(
         autotune.get_op("vadvc_update"), prog.grid_shape, prog.dtype,
         n_fields=prog.n_fields, tile=tile, k_steps=plan.k_steps)
@@ -987,7 +1003,8 @@ def _hadv_shard_local(plan):
             # The compute grid the planner tuned on is symmetrically
             # padded; the actual slab only grows on the low sides — snap
             # the window to it (the kernel is bitwise tile-invariant).
-            ty_l = tiling.snap_to_divisor(ty, Y, lo=1)
+            ty_l = tiling.snap_to_divisor(ty, Y,
+                                          align=tiling.sublanes(fs.dtype))
             fs = hadv_pallas(fs.reshape(-1, Y, X), cfl=cfl, ty=ty_l,
                              interpret=interp).reshape(fs.shape)
         out = fs[..., hy_lo:hy_lo + ly, hx_lo:hx_lo + lx]
@@ -1020,7 +1037,9 @@ def _hadv_apply_stage(prog, names, interpret, use_ref):
 def _hadv_traffic(plan, model_ty):
     prog = plan.program
     nz, ny, nx = prog.grid_shape
-    tile = (1, tiling.snap_to_divisor(model_ty, ny, lo=1), nx)
+    tile = (1, tiling.snap_to_divisor(model_ty, ny,
+                                      align=tiling.sublanes(prog.dtype)),
+            nx)
     return memmodel.stencil_op_traffic(
         autotune.get_op("hadv_upwind"), prog.grid_shape, prog.dtype,
         n_fields=prog.n_fields, tile=tile, k_steps=plan.k_steps)
@@ -1088,7 +1107,9 @@ def _asselin_apply_stage(prog, names, interpret, use_ref):
 def _asselin_traffic(plan, model_ty):
     prog = plan.program
     nz, ny, nx = prog.grid_shape
-    tile = (1, tiling.snap_to_divisor(model_ty, ny, lo=1), nx)
+    tile = (1, tiling.snap_to_divisor(model_ty, ny,
+                                      align=tiling.sublanes(prog.dtype)),
+            nx)
     return memmodel.stencil_op_traffic(
         autotune.get_op("asselin"), prog.grid_shape, prog.dtype,
         n_fields=prog.n_fields, tile=tile, k_steps=plan.k_steps)

@@ -47,7 +47,6 @@ def test_int8_unbiased():
 
 def test_compressed_psum_single_axis():
     """shard_map DP reduction with all 3 codecs on a 1-wide axis."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = make_mesh((1,), ("dp",))
     g = {"w": jnp.arange(8.0).reshape(2, 4)}
@@ -57,8 +56,8 @@ def test_compressed_psum_single_axis():
             return compression.compressed_psum(
                 t, "dp", method, key=jax.random.PRNGKey(0))
 
-        out = shard_map(f, mesh=mesh, in_specs=({"w": P()},),
-                        out_specs={"w": P()})(g)
+        out = jax.shard_map(f, mesh=mesh, in_specs=({"w": P()},),
+                            out_specs={"w": P()})(g)
         np.testing.assert_allclose(np.asarray(out["w"]),
                                    np.asarray(g["w"]), rtol=2e-2, atol=5e-2)
 

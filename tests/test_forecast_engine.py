@@ -266,8 +266,7 @@ def save_results(path, results):
 _DIST_SERVE_SNIPPET = _WORKLOAD_SNIPPET + r"""
 from repro.weather import domain
 from repro.weather.program import plan_cache_key
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
+kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
 grid = (4, 16, 16)
 prog = StencilProgram(grid_shape=grid, ensemble=1)

@@ -17,10 +17,7 @@ from repro.core import hlo_cost
 
 
 def _cost(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict in jax >= 0.5, a one-element
-    list of dicts in 0.4.x."""
-    c = compiled.cost_analysis()
-    return c[0] if isinstance(c, (list, tuple)) else c
+    return compiled.cost_analysis()
 
 
 def _body(c, _):

@@ -78,9 +78,14 @@ def test_fused_matches_unfused_ref(shape, rng):
 
 def test_nondivisible_tile_request_snaps(rng):
     """A requested y-window that does not divide ny must snap to a legal
-    divisor instead of erroring (ISSUE: non-divisible tile sizes)."""
-    assert ops.snap_ty(5, 16) == 4
-    assert ops.snap_ty(7, 12) == 6
+    window instead of erroring (non-divisible tile sizes): a
+    sublane-aligned divisor (8 rows fp32, 16 bf16) or the whole extent —
+    the only blocks the TPU compiler accepts."""
+    assert ops.snap_ty(5, 16) == 8     # no aligned divisor <= 5: next up
+    assert ops.snap_ty(20, 48) == 16
+    assert ops.snap_ty(20, 48, "bfloat16") == 16
+    assert ops.snap_ty(8, 32, "bfloat16") == 16
+    assert ops.snap_ty(7, 12) == 12    # no aligned divisor -> whole-y
     assert ops.snap_ty(6, 7) == 7      # prime ny -> whole-y window
     f, wcon, ut, us = _inputs(rng, (3, 14, 8))
     want_f, want_s, f2 = _ref_with_f2(f, wcon, ut, us)
@@ -361,13 +366,14 @@ def test_kstep_single_launch_trace():
 
 
 def test_kstep_ty_snapping_and_validity_bound():
-    """snap_ty_kstep: a divisor of ny respecting ty >= k*HALO; too-small
-    requests snap UP (the validity front needs the room), impossible grids
-    refuse loudly."""
+    """snap_ty_kstep: a sublane-aligned divisor of ny (or ny) respecting
+    ty >= k*HALO; too-small requests snap UP (the validity front needs the
+    room), impossible grids refuse loudly."""
     assert ops.snap_ty_kstep(8, 16, 2) == 8
-    assert ops.snap_ty_kstep(5, 16, 2) == 4      # largest divisor <= 5, >= 4
+    assert ops.snap_ty_kstep(5, 16, 2) == 8      # no aligned window <= 5
     assert ops.snap_ty_kstep(2, 16, 3) == 8      # snaps UP past k*HALO=6
-    assert ops.snap_ty_kstep(2, 14, 3) == 7      # prime-ish ny
+    assert ops.snap_ty_kstep(24, 48, 5) == 24    # aligned, >= k*HALO=10
+    assert ops.snap_ty_kstep(2, 14, 3) == 14     # no aligned divisor: ny
     with pytest.raises(ValueError):
         ops.snap_ty_kstep(4, 4, 3)               # ny < k*HALO: no window
     with pytest.raises(ValueError):
@@ -387,8 +393,8 @@ def test_kstep_vmem_budget_rejection():
     budget."""
     with pytest.raises(ValueError, match="VMEM|vmem|fit|legal"):
         ops.plan_tile_kstep((128, 8, 1024), jnp.float32, 4, 4)
-    # the same grid at k=1 window granularity is plannable
-    assert ops.plan_tile((128, 8, 1024), jnp.float32) >= 2
+    # a k=1 window at the paper's z and x extents is plannable
+    assert ops.plan_tile((64, 8, 256), jnp.float32) >= 2
 
 
 def test_kstep_tile_space_registered():
@@ -408,7 +414,8 @@ def test_whole_state_tile_space_registered():
     ty = ops.plan_tile_whole_state((8, 16, 32), jnp.float32, 4)
     assert 16 % ty == 0 and 2 <= ty <= 16
     spec = autotune.get_op("dycore_whole_state")
-    assert spec.scratch_fields == 7          # 6 temporaries + resident w
+    # 6 padded temporaries; the shared w is a 4th three-window stream
+    assert spec.scratch_fields == 6 and spec.input_windows == 3
     assert abs(spec.fields_in - (3 + 1 / 4)) < 1e-9
     # planning for another field count tunes its own space without
     # clobbering the registered default

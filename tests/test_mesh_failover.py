@@ -80,8 +80,7 @@ from repro.weather import program as wprog
 from repro.weather.program import StencilProgram
 
 def make_mesh(py, px):
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-          if hasattr(jax.sharding, "AxisType") else {})
+    kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
     return jax.make_mesh((py, px), ("data", "model"), **kw)
 """
 

@@ -288,8 +288,7 @@ from repro.weather import domain, fields
 from repro.weather.program import StencilProgram, compile
 from repro.weather.pipeline import PipelineProgram
 
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
+kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
 grid = (4, 16, 16)
 st = fields.initial_state(jax.random.PRNGKey(0), grid, ensemble=2)

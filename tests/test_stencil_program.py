@@ -117,21 +117,27 @@ def test_unfused_per_op_reports_model_legal_tiles():
 def test_registered_tile_spaces_and_snap_drift():
     """Satellite: the standalone hdiff/vadvc OpSpecs live in the autotune
     registry and their ops.plan_tile paths use the unified
-    `tiling.snap_to_divisor` rule (largest divisor below the tuned
-    extent) — no more private halving loops that drifted from
-    `resolve_tile`."""
+    `tiling.snap_to_divisor` rule (the largest TPU-aligned divisor below
+    the tuned extent, else the whole extent) — no more private halving
+    loops that drifted from `resolve_tile`."""
     assert autotune.get_op("hdiff") is tiling.HDIFF
     assert autotune.get_op("vadvc") is tiling.VADVC
     for ny in (8, 12, 14, 32, 96):
         ty = hdiff_ops.plan_tile((8, ny, 16), "float32")
         assert ny % ty == 0 and ty >= 2, (ny, ty)
-    for ny, nx in ((8, 16), (12, 24), (6, 14)):
+        assert ty % 8 == 0 or ty == ny, (ny, ty)
+    for ny, nx in ((8, 16), (12, 24), (6, 14), (32, 512)):
         tj, ti = vadvc_ops.plan_tile((8, ny, nx), "float32")
         assert ny % tj == 0 and nx % ti == 0, (ny, nx, tj, ti)
-    assert tiling.snap_to_divisor(5, 16, lo=2) == 4
-    assert tiling.snap_to_divisor(7, 12, lo=2) == 6
-    assert tiling.snap_to_divisor(6, 7, lo=2) == 7   # prime: whole extent
-    assert tiling.snap_to_divisor(24, 32, lo=1) == 16
+        assert tj % 8 == 0 or tj == ny, (ny, tj)
+        assert ti % 128 == 0 or ti == nx, (nx, ti)
+    assert tiling.snap_to_divisor(5, 16, align=8, lo=2) == 8
+    assert tiling.snap_to_divisor(7, 12, align=8, lo=2) == 12
+    assert tiling.snap_to_divisor(6, 7, align=8, lo=2) == 7  # whole extent
+    assert tiling.snap_to_divisor(24, 32, align=8) == 16
+    assert tiling.snap_to_divisor(300, 512, align=128) == 256
+    assert tiling.sublanes("float32") == 8
+    assert tiling.sublanes("bfloat16") == 16
 
 
 # ---------------------------------------------------------------------------
@@ -291,8 +297,7 @@ import jax, numpy as np
 from repro.core import trace_stats
 from repro.weather import domain, fields
 from repro.weather.program import StencilProgram, compile
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
+kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
 grid = (4, 16, 16)
 st = fields.initial_state(jax.random.PRNGKey(0), grid, ensemble=2)

@@ -79,8 +79,7 @@ from repro.weather import fields, domain
 from repro.weather.program import DycoreProgram, compile_dycore
 key = jax.random.PRNGKey(0)
 st = fields.initial_state(key, (6, 8, 8), ensemble=2)
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
+kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
 outs = {}
 for variant in ("whole_state", "per_field", "unfused"):
@@ -124,8 +123,7 @@ from repro.weather.program import DycoreProgram, compile_dycore
 K = 2
 grid = (4, 8, 16)
 st = fields.initial_state(jax.random.PRNGKey(1), grid, ensemble=2)
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
+kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 mesh = jax.make_mesh((2, 2), ("data", "model"), **kw)
 def plan_for(variant="auto", k=1, **kwargs):
     return compile_dycore(DycoreProgram(grid_shape=grid, ensemble=2,
