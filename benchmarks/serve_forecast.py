@@ -14,22 +14,19 @@ Reported metrics (docs/benchmarks.md, "BENCH_serve.json"):
   serve_forecast/occupancy          mean busy-slot fraction per round
   serve_forecast/cache_hit_rate     plan-cache hits / requests
 
-Plus the supervision overhead and recovery numbers (ISSUE 7):
-  serve_forecast/guard_overhead     validity-guard walltime / round
-                                    walltime on a service-scale grid
+Plus the supervision recovery numbers:
   serve_forecast/recovery_rounds    rounds the chaos engine kept serving
                                     after its first injected fault
 
 Also writes BENCH_serve.json: the latency distribution, per-request
 steps/s, batch occupancy, plan-cache hit statistics, the program catalog,
-the load spec, a `robustness` block (guard overhead + a deterministic
-chaos segment: one poisoned request, one device loss, one forced lowering
-fallback), and a `failover` block (ISSUE 8: a kill-a-device run on a
-forced-4-device subprocess — recovery rounds, requests preserved across
-the mesh rebuild, reshard wall time, and whether every preserved request
-stayed bit-identical to a solo run on the original mesh) — everything
-the CI smoke job asserts on and cross-PR perf diffs read.  BENCH_SMOKE=1
-shrinks the request count and slot pool.
+the load spec, a `robustness` block (a deterministic chaos segment: one
+poisoned request, one device loss, one forced lowering fallback), and a
+`failover` block (a kill-a-device run on a forced-4-device subprocess —
+recovery rounds, requests preserved across the mesh rebuild, reshard wall
+time, and whether every preserved request stayed bit-identical to a solo
+run on the original mesh) — everything the CI smoke job asserts on.
+BENCH_SMOKE=1 shrinks the request count and slot pool.
 """
 
 from __future__ import annotations
@@ -47,7 +44,6 @@ from benchmarks.common import emit, smoke_mode, write_json
 from repro.serve.forecast import ForecastEngine, ForecastRequest
 from repro.testing.faults import FaultInjector, FaultSpec
 from repro.weather import fields
-from repro.weather import program as wprog
 from repro.weather.program import StencilProgram
 
 # The served catalog: three programs a real mesoscale service would mix —
@@ -79,34 +75,6 @@ def _drive(eng: ForecastEngine, requests, arrivals):
             time.sleep(max(0.0, pending[0][0]
                            - (time.perf_counter() - t0)))
     return eng.drain()
-
-
-def _median_s(f, n):
-    jax.block_until_ready(f())                   # warm (compile + caches)
-    ts = []
-    for _ in range(n):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f())
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts))
-
-
-def _guard_overhead(smoke: bool) -> dict:
-    """Validity-guard cost as a fraction of round walltime, on a
-    service-scale grid (the smoke catalog's toy grids are dispatch-bound,
-    which would measure launch overhead, not the guard)."""
-    grid, slots = (8, 48, 48), 4
-    key = wprog.plan_cache_key(StencilProgram(grid_shape=grid, op="dycore"),
-                               ensemble=slots)
-    plan = wprog.compile(key)
-    batch = fields.initial_state(jax.random.PRNGKey(7), grid,
-                                 ensemble=slots)
-    n = 3 if smoke else 7
-    round_s = _median_s(lambda: plan.step(batch), n)
-    guard_s = _median_s(lambda: wprog.slot_validity(batch, 1e6), n)
-    return {"grid": list(grid), "slots": slots,
-            "round_us": round_s * 1e6, "guard_us": guard_s * 1e6,
-            "guard_overhead_frac": guard_s / round_s}
 
 
 def _chaos_segment(slots: int) -> dict:
@@ -258,12 +226,8 @@ def run() -> None:
     emit("serve_forecast/cache_hit_rate", cache["hit_rate"],
          f"{len(_CATALOG)} programs, {cache['misses']} compiles")
 
-    guard = _guard_overhead(smoke)
     chaos = _chaos_segment(slots)
     failover = _failover_segment()
-    emit("serve_forecast/guard_overhead", guard["guard_overhead_frac"],
-         f"guard {guard['guard_us']:.0f}us / round "
-         f"{guard['round_us']:.0f}us on {tuple(guard['grid'])}")
     emit("serve_forecast/recovery_rounds", chaos["recovery_rounds"],
          f"{chaos['faults_fired']} faults, "
          f"{chaos['quarantined']} quarantined")
@@ -283,7 +247,7 @@ def run() -> None:
                                     "min": float(np.min(sps))},
         "occupancy": stats["occupancy"],
         "plan_cache": cache,
-        "robustness": {**guard, **chaos},
+        "robustness": chaos,
         "failover": failover,
         "programs": [p.to_json() for p in _CATALOG],
         "load": {"model": "open-loop poisson", "seed": 42,

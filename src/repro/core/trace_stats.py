@@ -10,7 +10,7 @@ never lowers to a custom call that HLO-level counting could find.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 
 def _sub_jaxprs(eqn) -> list:
@@ -96,3 +96,22 @@ def primitive_counts(jaxpr) -> Dict[str, int]:
 
     walk(jaxpr)
     return out
+
+
+def pallas_call_names(jaxpr) -> Tuple[str, ...]:
+    """The `name` of each distinct `pallas_call` in `jaxpr`, in program
+    order (recursing like `count_primitive`): the names the kernels carry
+    in a device profile, where an instance reads `<name>.<n>`."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    out: list = []
+
+    def walk(j: Any) -> None:
+        for eqn in j.eqns:
+            if (eqn.primitive.name == "pallas_call"
+                    and eqn.params["name"] not in out):
+                out.append(eqn.params["name"])
+            for sub in _sub_jaxprs(eqn):
+                walk(sub)
+
+    walk(jaxpr)
+    return tuple(out)

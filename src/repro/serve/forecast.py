@@ -110,6 +110,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -132,6 +133,10 @@ __all__ = ["ForecastRequest", "ForecastResult", "ForecastEngine",
 #              failure; `diagnosis` says why, `state` is the last state
 #   expired  — per-request deadline passed before completion
 STATUSES = ("ok", "failed", "expired")
+
+# The engine's spans on the profiler's clock (docs/serving.md, "Tracing a
+# live engine").  A span costs one check when no profiler is recording.
+_span = jax.profiler.TraceAnnotation
 
 
 class QueueFullError(RuntimeError):
@@ -231,6 +236,12 @@ class _Lane:
     # reshard and keep guarding across it.  Entries are dropped whenever a
     # slot's bits legitimately get new content (admit, scrub).
     fps: Dict[int, int] = dataclasses.field(default_factory=dict)
+
+    @functools.cached_property
+    def slot_bytes(self) -> int:
+        """Bytes of one slot's state: what a retire reads back."""
+        return sum(a.nbytes for a in jax.tree_util.tree_leaves(self.batch)
+                   ) // len(self.slots)
 
 
 @dataclasses.dataclass
@@ -347,17 +358,18 @@ class ForecastEngine:
         set (and a ckpt_dir), the watchdog auto-checkpoints at the pump
         boundary — every lane sits at a round boundary there, so a crash
         resumes bitwise-equal to an uninterrupted run."""
-        self._admit()
-        for lane in self._lanes.values():
-            if any(s is not None for s in lane.slots):
-                self._round(lane)
-        if (self.ckpt_every_rounds and self.ckpt_dir is not None
-                and self._stats["rounds"] - self._last_ckpt_round
-                >= self.ckpt_every_rounds):
-            self.checkpoint()
-            self._last_ckpt_round = self._stats["rounds"]
-            self._stats["watchdog_checkpoints"] += 1
-        return self.has_work()
+        with _span("forecast.pump", round=self._stats["rounds"]):
+            self._admit()
+            for lane in self._lanes.values():
+                if any(s is not None for s in lane.slots):
+                    self._round(lane)
+            if (self.ckpt_every_rounds and self.ckpt_dir is not None
+                    and self._stats["rounds"] - self._last_ckpt_round
+                    >= self.ckpt_every_rounds):
+                self.checkpoint()
+                self._last_ckpt_round = self._stats["rounds"]
+                self._stats["watchdog_checkpoints"] += 1
+            return self.has_work()
 
     def drain(self) -> Dict[int, ForecastResult]:
         """Pump until idle; returns ALL results finished so far."""
@@ -398,40 +410,42 @@ class ForecastEngine:
     def _plan_for(self, key: _wprog.StencilProgram) -> _wprog.ExecutionPlan:
         plan = self._plans.get(key)
         if plan is None:
-            ax_e, ax_y, ax_x = self.mesh_axes
-            inj = self.fault_injector
-            prog = key
-            pinned = self._pinned.get(key)
-            if pinned is not None:
-                # Recompiling an already-served program (failover/elastic
-                # restore): pin the FIRST resolution's round strategy so
-                # in-flight canonical round sequences stay intact.  If the
-                # pinned depth cannot compile on this mesh (e.g. a deep k
-                # on a tiny shard), fall back to re-resolving — requests
-                # still complete, bit-identity becomes best-effort, and
-                # `plan_repins` records that it happened.
-                prog = dataclasses.replace(key, variant=pinned["variant"],
-                                           k_steps=pinned["k_steps"])
-                try:
-                    _wprog.compile(prog, mesh=self.mesh, ax_e=ax_e,
-                                   ax_y=ax_y, ax_x=ax_x,
-                                   interpret=self.interpret)
-                except Exception:  # noqa: BLE001 — planner rejection
-                    self._stats["plan_repins"] += 1
-                    prog = key
-            # Compile through the fallback chain (native -> interpret ->
-            # reference lowering), via the module so a test spy on
-            # repro.weather.program.compile observes every compilation.
-            plan, fallback, errors = _wprog.compile_with_fallback(
-                prog, mesh=self.mesh, ax_e=ax_e, ax_y=ax_y, ax_x=ax_x,
-                interpret=self.interpret,
-                attempt_hook=inj.on_compile if inj is not None else None)
-            if fallback is not None:
-                self._stats["fallback_compiles"] += 1
-                self._fallbacks[key] = {"stage": fallback, "errors": errors}
-            self._plans[key] = plan
-            self._pinned.setdefault(
-                key, {"variant": plan.variant, "k_steps": plan.k_steps})
+            with _span("forecast.compile"):
+                ax_e, ax_y, ax_x = self.mesh_axes
+                inj = self.fault_injector
+                prog = key
+                pinned = self._pinned.get(key)
+                if pinned is not None:
+                    # Recompiling an already-served program (failover/elastic
+                    # restore): pin the FIRST resolution's round strategy so
+                    # in-flight canonical round sequences stay intact.  If the
+                    # pinned depth cannot compile on this mesh (e.g. a deep k
+                    # on a tiny shard), fall back to re-resolving — requests
+                    # still complete, bit-identity becomes best-effort, and
+                    # `plan_repins` records that it happened.
+                    prog = dataclasses.replace(key, variant=pinned["variant"],
+                                               k_steps=pinned["k_steps"])
+                    try:
+                        _wprog.compile(prog, mesh=self.mesh, ax_e=ax_e,
+                                       ax_y=ax_y, ax_x=ax_x,
+                                       interpret=self.interpret)
+                    except Exception:  # noqa: BLE001 — planner rejection
+                        self._stats["plan_repins"] += 1
+                        prog = key
+                # Compile through the fallback chain (native -> interpret ->
+                # reference lowering), via the module so a test spy on
+                # repro.weather.program.compile observes every compilation.
+                plan, fallback, errors = _wprog.compile_with_fallback(
+                    prog, mesh=self.mesh, ax_e=ax_e, ax_y=ax_y, ax_x=ax_x,
+                    interpret=self.interpret,
+                    attempt_hook=inj.on_compile if inj is not None else None)
+                if fallback is not None:
+                    self._stats["fallback_compiles"] += 1
+                    self._fallbacks[key] = {"stage": fallback,
+                                            "errors": errors}
+                self._plans[key] = plan
+                self._pinned.setdefault(
+                    key, {"variant": plan.variant, "k_steps": plan.k_steps})
         return plan
 
     def _lane_for(self, key: _wprog.StencilProgram) -> _Lane:
@@ -504,10 +518,11 @@ class ForecastEngine:
         for key, wave in waves.items():
             lane = self._lanes[key]
             idx = [i for i, _ in wave]
-            sub = jax.tree_util.tree_map(
-                lambda *xs: jnp.concatenate(xs, axis=0),
-                *[p.request.state for _, p in wave])
-            lane.batch = self._assign(lane.batch, jnp.asarray(idx), sub)
+            with _span("forecast.admit", slots=len(wave), queued=len(keep)):
+                sub = jax.tree_util.tree_map(
+                    lambda *xs: jnp.concatenate(xs, axis=0),
+                    *[p.request.state for _, p in wave])
+                lane.batch = self._assign(lane.batch, jnp.asarray(idx), sub)
             admit_t = time.perf_counter()
             for i, pend in wave:
                 lane.fps.pop(i, None)   # fresh content in this slot
@@ -538,47 +553,51 @@ class ForecastEngine:
         kk = min(parts.values())
         participants = [i for i, p in parts.items() if p == kk]
         rnd = self._stats["rounds"]
-        prev = lane.batch if len(participants) < len(parts) else None
-        new_batch = self._step_with_retry(lane, plan, kk, rnd)
-        if new_batch is None:                    # escalation exhausted
-            if self._try_failover(lane, rnd):
-                return          # round re-ran on the rebuilt mesh
-            self._fail_lane(lane, rnd)
-            return
-        lane.batch = new_batch
-        if prev is not None:
-            mask = np.zeros(self.slots, bool)
-            mask[participants] = True
-            lane.batch = _wprog.ensemble_slot_select(mask, lane.batch, prev)
-            self._stats["rolled_back_slot_rounds"] += (
-                len(parts) - len(participants))
-        self._stats["rounds"] += 1
-        self._stats["occupancy_sum"] += len(parts) / self.slots
-        self._stats["occupancy_samples"] += 1
-        inj = self.fault_injector
-        if inj is not None:
-            nonparts = tuple(i for i in range(self.slots)
-                             if i not in set(participants))
-            lane.batch = inj.poison(lane.batch, lane.key.op, rnd,
-                                    tuple(parts), nonparticipants=nonparts,
-                                    shards=plan.shards)
-        bad = (self._guard_check(lane, parts, participants, rnd)
-               if self.guard else {})
-        for i, (diag, state) in bad.items():
-            self._quarantine(lane, i, diag, state)
-        for i in participants:
-            if i in bad:
-                continue
-            slot = lane.slots[i]
-            slot.remaining -= kk
-            slot.rounds += 1
-            if slot.remaining == 0:
-                self._retire(lane, i)
-        now = time.perf_counter()
-        for i, slot in enumerate(lane.slots):
-            if (slot is not None and slot.deadline_s is not None
-                    and now - slot.submit_t > slot.deadline_s):
-                self._expire_slot(lane, i, now)
+        with _span("forecast.round", round=rnd, active=len(parts), kk=kk):
+            prev = lane.batch if len(participants) < len(parts) else None
+            new_batch = self._step_with_retry(lane, plan, kk, rnd)
+            if new_batch is None:                    # escalation exhausted
+                if self._try_failover(lane, rnd):
+                    return          # round re-ran on the rebuilt mesh
+                self._fail_lane(lane, rnd)
+                return
+            lane.batch = new_batch
+            if prev is not None:
+                mask = np.zeros(self.slots, bool)
+                mask[participants] = True
+                lane.batch = _wprog.ensemble_slot_select(mask, lane.batch,
+                                                         prev)
+                self._stats["rolled_back_slot_rounds"] += (
+                    len(parts) - len(participants))
+            self._stats["rounds"] += 1
+            self._stats["occupancy_sum"] += len(parts) / self.slots
+            self._stats["occupancy_samples"] += 1
+            inj = self.fault_injector
+            if inj is not None:
+                nonparts = tuple(i for i in range(self.slots)
+                                 if i not in set(participants))
+                lane.batch = inj.poison(lane.batch, lane.key.op, rnd,
+                                        tuple(parts), nonparticipants=nonparts,
+                                        shards=plan.shards)
+            bad = {}
+            if self.guard:
+                with _span("forecast.guard", round=rnd):
+                    bad = self._guard_check(lane, parts, participants, rnd)
+            for i, (diag, state) in bad.items():
+                self._quarantine(lane, i, diag, state)
+            for i in participants:
+                if i in bad:
+                    continue
+                slot = lane.slots[i]
+                slot.remaining -= kk
+                slot.rounds += 1
+                if slot.remaining == 0:
+                    self._retire(lane, i)
+            now = time.perf_counter()
+            for i, slot in enumerate(lane.slots):
+                if (slot is not None and slot.deadline_s is not None
+                        and now - slot.submit_t > slot.deadline_s):
+                    self._expire_slot(lane, i, now)
 
     def _step_with_retry(self, lane: _Lane, plan, kk: int, rnd: int):
         """Run one round, retrying transient failures with exponential
@@ -594,26 +613,27 @@ class ForecastEngine:
         last = None
         for attempt in range(self.max_round_retries + 1):
             try:
-                t0 = time.perf_counter()
-                if inj is not None:
-                    inj.on_round(lane.key.op, rnd,
-                                 device_ids=self._device_ids())
-                out = plan.round_plan(kk).step(lane.batch)
-                if (self.guard or inj is not None
-                        or self.round_deadline_s is not None):
-                    # Surface async runtime failures HERE, inside the
-                    # retry scope, rather than at some later readback
-                    # (the guard reads the batch right after anyway).
-                    jax.block_until_ready(out)
-                if (self.round_deadline_s is not None
-                        and time.perf_counter() - t0
-                        > self.round_deadline_s):
-                    self._stats["round_deadline_hits"] += 1
-                    raise RoundDeadlineError(
-                        f"round {rnd} attempt took "
-                        f"{time.perf_counter() - t0:.3f}s > "
-                        f"round_deadline_s={self.round_deadline_s}")
-                return out
+                with _span("forecast.step", round=rnd, attempt=attempt):
+                    t0 = time.perf_counter()
+                    if inj is not None:
+                        inj.on_round(lane.key.op, rnd,
+                                     device_ids=self._device_ids())
+                    out = plan.round_plan(kk).step(lane.batch)
+                    if (self.guard or inj is not None
+                            or self.round_deadline_s is not None):
+                        # Surface async runtime failures HERE, inside the
+                        # retry scope, rather than at some later readback
+                        # (the guard reads the batch right after anyway).
+                        jax.block_until_ready(out)
+                    if (self.round_deadline_s is not None
+                            and time.perf_counter() - t0
+                            > self.round_deadline_s):
+                        self._stats["round_deadline_hits"] += 1
+                        raise RoundDeadlineError(
+                            f"round {rnd} attempt took "
+                            f"{time.perf_counter() - t0:.3f}s > "
+                            f"round_deadline_s={self.round_deadline_s}")
+                    return out
             except Exception as e:  # noqa: BLE001 — supervised boundary
                 self._stats["round_retries"] += 1
                 last = e
@@ -631,8 +651,10 @@ class ForecastEngine:
                 ax_e, ax_y, ax_x = self.mesh_axes
                 plan2 = _wprog.compile(lane.key, mesh=self.mesh, ax_e=ax_e,
                                        ax_y=ax_y, ax_x=ax_x, interpret=True)
-                out = plan2.round_plan(kk).step(lane.batch)
-                jax.block_until_ready(out)
+                with _span("forecast.step", round=rnd,
+                           attempt=self.max_round_retries + 1):
+                    out = plan2.round_plan(kk).step(lane.batch)
+                    jax.block_until_ready(out)
                 self._plans[lane.key] = plan2
                 self._fallbacks[lane.key] = {
                     "stage": "interpret", "errors": [("runtime", repr(last))]}
@@ -859,14 +881,16 @@ class ForecastEngine:
         is re-zeroed so the lane stays healthy, and the freed slot
         backfills from the queue at the next admit."""
         slot = lane.slots[i]
-        lane.slots[i] = None
-        self._stats["quarantined"] += 1
-        self._scrub(lane, i)
-        self._finish(slot.rid, dataclasses.replace(lane.key, ensemble=1),
-                     state, steps=slot.steps, admit_t=slot.admit_t,
-                     queue_wait_s=slot.queue_wait_s, rounds=slot.rounds,
-                     status="failed",
-                     steps_done=slot.steps - slot.remaining, diagnosis=diag)
+        with _span("forecast.quarantine", rid=slot.rid):
+            lane.slots[i] = None
+            self._stats["quarantined"] += 1
+            self._scrub(lane, i)
+            self._finish(slot.rid, dataclasses.replace(lane.key, ensemble=1),
+                         state, steps=slot.steps, admit_t=slot.admit_t,
+                         queue_wait_s=slot.queue_wait_s, rounds=slot.rounds,
+                         status="failed",
+                         steps_done=slot.steps - slot.remaining,
+                         diagnosis=diag)
 
     def _scrub(self, lane: _Lane, i: int) -> None:
         zero = _fields.zeros_state(lane.key.grid_shape, ensemble=1,
@@ -877,31 +901,33 @@ class ForecastEngine:
 
     def _expire_slot(self, lane: _Lane, i: int, now: float) -> None:
         slot = lane.slots[i]
-        lane.slots[i] = None
-        self._stats["deadline_expired"] += 1
-        state = jax.tree_util.tree_map(
-            np.asarray, _wprog.ensemble_slot_view(lane.batch, i))
-        self._scrub(lane, i)
-        self._finish(slot.rid, dataclasses.replace(lane.key, ensemble=1),
-                     state, steps=slot.steps, admit_t=slot.admit_t,
-                     queue_wait_s=slot.queue_wait_s, rounds=slot.rounds,
-                     status="expired",
-                     steps_done=slot.steps - slot.remaining,
-                     diagnosis={"reason": "deadline_exceeded",
-                                "deadline_s": slot.deadline_s,
-                                "elapsed_s": now - slot.submit_t,
-                                "where": "in_flight"})
+        with _span("forecast.expire", rid=slot.rid):
+            lane.slots[i] = None
+            self._stats["deadline_expired"] += 1
+            state = jax.tree_util.tree_map(
+                np.asarray, _wprog.ensemble_slot_view(lane.batch, i))
+            self._scrub(lane, i)
+            self._finish(slot.rid, dataclasses.replace(lane.key, ensemble=1),
+                         state, steps=slot.steps, admit_t=slot.admit_t,
+                         queue_wait_s=slot.queue_wait_s, rounds=slot.rounds,
+                         status="expired",
+                         steps_done=slot.steps - slot.remaining,
+                         diagnosis={"reason": "deadline_exceeded",
+                                    "deadline_s": slot.deadline_s,
+                                    "elapsed_s": now - slot.submit_t,
+                                    "where": "in_flight"})
 
     def _retire(self, lane: _Lane, i: int) -> None:
         slot = lane.slots[i]
         lane.slots[i] = None
-        # Read back exactly this slot; blocking here IS the finish time.
-        state = jax.tree_util.tree_map(
-            np.asarray, _wprog.ensemble_slot_view(lane.batch, i))
-        prog = dataclasses.replace(lane.key, ensemble=1)
-        self._finish(slot.rid, prog, state, steps=slot.steps,
-                     admit_t=slot.admit_t, queue_wait_s=slot.queue_wait_s,
-                     rounds=slot.rounds)
+        with _span("forecast.retire", rid=slot.rid, bytes=lane.slot_bytes):
+            # Read back exactly this slot; blocking here IS the finish time.
+            state = jax.tree_util.tree_map(
+                np.asarray, _wprog.ensemble_slot_view(lane.batch, i))
+            prog = dataclasses.replace(lane.key, ensemble=1)
+            self._finish(slot.rid, prog, state, steps=slot.steps,
+                         admit_t=slot.admit_t,
+                         queue_wait_s=slot.queue_wait_s, rounds=slot.rounds)
 
     def _finish(self, rid: int, prog, state, *, steps: int, admit_t: float,
                 queue_wait_s: float, rounds: int, status: str = "ok",
@@ -979,8 +1005,9 @@ class ForecastEngine:
                 "diagnosis": r.diagnosis,
             } for r in self._results.values()],
         }
-        ckpt.save_tree(ckpt_dir, step, tree, extra=extra,
-                       keep=self.ckpt_keep)
+        with _span("forecast.checkpoint", step=step):
+            ckpt.save_tree(ckpt_dir, step, tree, extra=extra,
+                           keep=self.ckpt_keep)
         return step
 
     @classmethod

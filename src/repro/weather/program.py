@@ -49,7 +49,8 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import shard_map as _shard_map
-from repro.core import autotune, hwspec, memmodel, perfmodel, tiling
+from repro.core import (autotune, hwspec, memmodel, perfmodel, tiling,
+                        trace_stats)
 from repro.weather import stencil_ops as _sops
 from repro.weather.fields import PROGNOSTIC, WeatherState, zeros_state
 from repro.weather.stencil_ops import (StencilOpDef, get_stencil_op,
@@ -448,21 +449,25 @@ class ExecutionPlan:
         if not isinstance(steps, int) or steps < 0:
             raise ValueError(f"steps={steps!r} must be a non-negative int")
         self._check_state(state)
-        rounds, tail = divmod(steps, self.k_steps)
-        if rounds:
-            if self.mesh is None:
-                state = self._rounds_fn(rounds)(state)
-            else:
-                # Deliberately a Python loop, not a scan: each round is one
-                # jitted shard_map program, which keeps run() composable
-                # with host-side work between rounds (checkpoints, I/O) and
-                # keeps the traced round — what the structural tests and
-                # report() describe — the unit of execution.
-                step = self._step_fn()
-                for _ in range(rounds):
-                    state = step(state)
-        if tail:
-            state = self.round_plan(tail).step(state)
+        self.kernels()
+        with jax.profiler.TraceAnnotation(
+                "plan.run", steps=steps, kernels=self._cache["kernel_names"]):
+            rounds, tail = divmod(steps, self.k_steps)
+            if rounds:
+                if self.mesh is None:
+                    state = self._rounds_fn(rounds)(state)
+                else:
+                    # Deliberately a Python loop, not a scan: each round is
+                    # one jitted shard_map program, which keeps run()
+                    # composable with host-side work between rounds
+                    # (checkpoints, I/O) and keeps the traced round — what
+                    # the structural tests and report() describe — the
+                    # unit of execution.
+                    step = self._step_fn()
+                    for _ in range(rounds):
+                        state = step(state)
+            if tail:
+                state = self.round_plan(tail).step(state)
         return state
 
     def round_plan(self, k: int) -> "ExecutionPlan":
@@ -478,11 +483,29 @@ class ExecutionPlan:
             return self
         return self._tail_plan(k)
 
+    def kernels(self) -> Tuple[str, ...]:
+        """The `name` of each Pallas kernel one round launches, in launch
+        order, read off the traced round (`trace_stats.pallas_call_names`):
+        the names the kernels carry in a device profile.  Traced once per
+        plan, on its first `run` or `report()`."""
+        names = self._cache.get("kernels")
+        if names is None:
+            p = self.program
+            state = jax.eval_shape(lambda: zeros_state(
+                p.grid_shape, p.ensemble, p.dtype, names=p.fields))
+            names = trace_stats.pallas_call_names(
+                jax.make_jaxpr(self._step_fn())(state))
+            self._cache["kernels"] = names
+            # `,` and `=` delimit a span's metadata entries in a profile.
+            self._cache["kernel_names"] = ";".join(names)
+        return names
+
     def report(self) -> Dict[str, Any]:
         """Machine-readable strategy: the resolved op + variant + tile + k
-        + exchange, the op's declared footprint, the structural
-        launch/collective counts per round (verifiable against a traced
-        jaxpr via `trace_stats.assert_plan_structure`), and the modeled
+        + exchange, the op's declared footprint, the Pallas kernels a round
+        launches (`kernels()`), the structural launch/collective counts per
+        round (verifiable against a traced jaxpr via
+        `trace_stats.assert_plan_structure`), and the modeled
         HBM-traffic / wire-byte / GFLOPS numbers.  Plain JSON-serializable
         types only — benchmarks embed it verbatim."""
         prog = self.program
@@ -523,6 +546,7 @@ class ExecutionPlan:
             "compute_grid": list(self.compute_grid),
             "exchange": (None if self.exchange is None
                          else self.exchange.describe()),
+            "kernels": list(self.kernels()),
             "pallas_calls_per_round": self.pallas_calls_per_round,
             "collectives_per_round": self.collectives_per_round,
         }
@@ -654,7 +678,7 @@ class ExecutionPlan:
         if fn is None:
             step = self._step_fn()
 
-            @jax.jit
+            @_sops.jit_round(self.program.op, "rounds")
             def fn(state):
                 def body(s, _):
                     return step(s), ()
@@ -719,6 +743,18 @@ def compile(program: StencilProgram, mesh: Optional[Mesh] = None, *,
     if tune not in (None, "model", "measure"):
         raise ValueError(f"tune={tune!r}: expected None, 'model', or "
                          f"'measure'")
+    with jax.profiler.TraceAnnotation("plan.compile", op=program.op,
+                                      ensemble=program.ensemble):
+        return _resolve(program, mesh, ax_e=ax_e, ax_y=ax_y, ax_x=ax_x,
+                        interpret=interpret, prefetch_w=prefetch_w,
+                        tune=tune, _tile_ty=_tile_ty)
+
+
+def _resolve(program: StencilProgram, mesh: Optional[Mesh], *,
+             ax_e: Optional[str], ax_y: str, ax_x: str,
+             interpret: Optional[bool], prefetch_w: Optional[bool],
+             tune: Optional[str], _tile_ty: Optional[int]) -> ExecutionPlan:
+    """`compile`'s planner, after its argument checks."""
     opdef = get_stencil_op(program.op)
     nz, ny, nx = program.grid_shape
     nf = program.n_fields
@@ -1042,7 +1078,7 @@ def _build_local_step(plan: ExecutionPlan):
         return opdef.build_local_step(plan)
     local = opdef.build_shard_local(plan)
 
-    @jax.jit
+    @_sops.jit_round(plan.program.op)
     def step(state: WeatherState) -> WeatherState:
         new_fields, new_stage = local(state.fields, state.wcon,
                                       state.tens, state.stage_tens)
@@ -1064,7 +1100,7 @@ def _build_distributed_step(plan: ExecutionPlan):
                          in_specs=(spec, spec, spec, spec),
                          out_specs=(spec, spec))
 
-    @jax.jit
+    @_sops.jit_round(plan.program.op)
     def step(state: WeatherState) -> WeatherState:
         new_fields, new_stage = sharded(state.fields, state.wcon,
                                         state.tens, state.stage_tens)

@@ -50,6 +50,7 @@ both), which is how a chain keeps intermediates resident between stages.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -75,6 +76,19 @@ from repro.weather.dycore import HALO
 from repro.weather.fields import WeatherState
 
 VARIANTS = ("auto", "unfused", "per_field", "whole_state", "kstep")
+
+
+def jit_round(op: str, suffix: str = "round"):
+    """`jax.jit` as a decorator that names the compiled module
+    `jit_<op>_<suffix>` (the op's name with its punctuation folded to
+    `_`), so a profile's XLA Modules line tells a plan's rounds apart from
+    the serving engine's guard and admission modules."""
+    name = re.sub(r"\W+", "_", op).strip("_") + "_" + suffix
+
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+    return wrap
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,7 +283,7 @@ def _dycore_local_step(plan):
     unstack = lambda a: _dycore.unstack_state(a, names)
 
     if variant == "unfused":
-        @jax.jit
+        @jit_round(prog.op)
         def step(state: WeatherState) -> WeatherState:
             new_fields, new_stage = {}, {}
             for name in names:
@@ -287,7 +301,7 @@ def _dycore_local_step(plan):
         return step
 
     if variant == "per_field":
-        @jax.jit
+        @jit_round(prog.op)
         def step(state: WeatherState) -> WeatherState:
             new_fields, new_stage = {}, {}
             for name in names:
@@ -302,7 +316,7 @@ def _dycore_local_step(plan):
         return step
 
     if variant == "whole_state":
-        @jax.jit
+        @jit_round(prog.op)
         def step(state: WeatherState) -> WeatherState:
             f_new, stage = fused_ops.fused_step_whole_state(
                 stack(state.fields), state.wcon, stack(state.tens),
@@ -314,7 +328,7 @@ def _dycore_local_step(plan):
 
     k = plan.k_steps
 
-    @jax.jit
+    @jit_round(prog.op)
     def step(state: WeatherState) -> WeatherState:
         f_new, stage = fused_ops.fused_step_kstep(
             stack(state.fields), state.wcon, stack(state.tens),
