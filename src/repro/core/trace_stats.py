@@ -10,7 +10,7 @@ never lowers to a custom call that HLO-level counting could find.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 
 def _sub_jaxprs(eqn) -> list:
@@ -82,17 +82,39 @@ def assert_plan_structure(jaxpr, report: Dict[str, Any]) -> Dict[str, int]:
     return counts
 
 
-def primitive_counts(jaxpr) -> Dict[str, int]:
+def primitive_counts(jaxpr, kernels: bool = True) -> Dict[str, int]:
     """Histogram of every primitive in `jaxpr` (recursive, scan bodies
-    counted once)."""
+    counted once).  `kernels=False` counts each `pallas_call` as one
+    primitive and leaves its kernel body out: what the program does
+    around its launches."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     out: Dict[str, int] = {}
 
     def walk(j: Any) -> None:
         for eqn in j.eqns:
             out[eqn.primitive.name] = out.get(eqn.primitive.name, 0) + 1
-            for sub in _sub_jaxprs(eqn):
-                walk(sub)
+            if kernels or eqn.primitive.name != "pallas_call":
+                for sub in _sub_jaxprs(eqn):
+                    walk(sub)
+
+    walk(jaxpr)
+    return out
+
+
+def scan_bodies(jaxpr) -> List[Tuple[int, Any]]:
+    """`(unroll, body)` of each outermost `scan` in `jaxpr`, in program
+    order, outside kernel bodies: the body is one iteration's jaxpr, which
+    the compiled loop repeats `unroll` times per trip."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    out: List[Tuple[int, Any]] = []
+
+    def walk(j: Any) -> None:
+        for eqn in j.eqns:
+            if eqn.primitive.name == "scan":
+                out.append((eqn.params["unroll"], eqn.params["jaxpr"].jaxpr))
+            elif eqn.primitive.name != "pallas_call":
+                for sub in _sub_jaxprs(eqn):
+                    walk(sub)
 
     walk(jaxpr)
     return out

@@ -10,15 +10,16 @@ Two granularities:
 * `fused_step_whole_state(...)` — ALL prognostic fields in ONE `pallas_call`:
   fields are stacked on a leading `nf` axis, the shared staggered-velocity
   slab is DMA'd once per (ensemble, y-window) instead of once per field, and
-  the launch cost is amortized nf×.  The default (`variant="whole_state"`)
-  hot path of compiled dycore plans (`weather/program.py::compile`).
+  the launch cost is amortized nf×.  Compiled `whole_state` plans launch
+  the same kernel on their own stacked operands
+  (`weather/stencil_ops.py::_dycore_stacked_round`).
 * `fused_step_kstep(...)` — the whole k-step round in ONE `pallas_call`: the
   kernel body runs the k local steps internally, prognostic state between
   steps lives in VMEM scratch, and the shared `w` slab is double-buffer
   prefetched across y-windows (`kernels/dycore_fused/fused.py::
-  fused_dycore_kstep_pallas`).  The hot path of every `variant="kstep"`
-  dycore plan (`weather/program.py::compile`), single-chip and
-  distributed (the communication-avoiding mode).
+  fused_dycore_kstep_pallas`).  Compiled `kstep` plans, single-chip and
+  distributed (the communication-avoiding mode), launch that kernel on
+  their own stacked operands (`weather/stencil_ops.py`).
 
 Both default `interpret=None`, resolved via `_auto_interpret()`: native
 Pallas on TPU, interpreter everywhere else.
@@ -45,6 +46,12 @@ DEFAULT_DT = _ref.DEFAULT_DT
 def _auto_interpret() -> bool:
     """Pallas runs natively on TPU, in interpreter mode everywhere else."""
     return jax.default_backend() != "tpu"
+
+
+def staggered_w(wcon: jnp.ndarray) -> jnp.ndarray:
+    """The kernels' staggered vertical velocity `wcon_i + wcon_{i+1}`,
+    periodic in x, from the unstaggered `wcon`."""
+    return wcon + jnp.roll(wcon, -1, axis=-1)
 
 
 def snap_ty(ty: int, ny: int, dtype=jnp.float32) -> int:
@@ -155,7 +162,7 @@ def fused_step(f: jnp.ndarray, wcon: jnp.ndarray, utens: jnp.ndarray,
         interpret = _auto_interpret()
     ny = f.shape[-2]
     ty = snap_ty(ty, ny, f.dtype) if ty else plan_tile(f.shape[-3:], f.dtype)
-    w = wcon + jnp.roll(wcon, -1, axis=-1)   # wcon_i + wcon_{i+1}, periodic
+    w = staggered_w(wcon)
     return fused_dycore_pallas(f, w, utens, utens_stage, coeff=coeff, dt=dt,
                                ty=ty, interpret=interpret)
 
@@ -181,7 +188,7 @@ def fused_step_whole_state(fs: jnp.ndarray, wcon: jnp.ndarray,
     nf, _, ny, _ = fs.shape[-4:]
     ty = (snap_ty(ty, ny, fs.dtype) if ty
           else plan_tile_whole_state(fs.shape[-3:], fs.dtype, nf))
-    w = wcon + jnp.roll(wcon, -1, axis=-1)   # wcon_i + wcon_{i+1}, periodic
+    w = staggered_w(wcon)
     return fused_dycore_whole_state_pallas(fs, w, utens, utens_stage,
                                            coeff=coeff, dt=dt, ty=ty,
                                            interpret=interpret)
@@ -208,7 +215,7 @@ def fused_step_kstep(fs: jnp.ndarray, wcon: jnp.ndarray,
     nf, _, ny, _ = fs.shape[-4:]
     ty = (snap_ty_kstep(ty, ny, k_steps, fs.dtype) if ty
           else plan_tile_kstep(fs.shape[-3:], fs.dtype, nf, k_steps))
-    w = wcon + jnp.roll(wcon, -1, axis=-1)   # wcon_i + wcon_{i+1}, periodic
+    w = staggered_w(wcon)
     return fused_dycore_kstep_pallas(fs, w, utens, utens_stage,
                                      k_steps=k_steps, coeff=coeff, dt=dt,
                                      ty=ty, interpret=interpret,

@@ -445,13 +445,15 @@ class ExecutionPlan:
         """Advance `steps` timesteps: `steps // k_steps` full rounds plus,
         when `steps % k_steps != 0`, one shorter TAIL round at
         `k' = steps mod k_steps` (a derived plan, compiled on demand) —
-        no step count is rejected."""
+        no step count is rejected.  On a single chip the full rounds are
+        one jitted scan whose carry `_round_carry` picks."""
         if not isinstance(steps, int) or steps < 0:
             raise ValueError(f"steps={steps!r} must be a non-negative int")
         self._check_state(state)
         self.kernels()
         with jax.profiler.TraceAnnotation(
-                "plan.run", steps=steps, kernels=self._cache["kernel_names"]):
+                "plan.run", steps=steps, kernels=self._cache["kernel_names"],
+                carry=self._round_carry()[0]):
             rounds, tail = divmod(steps, self.k_steps)
             if rounds:
                 if self.mesh is None:
@@ -547,6 +549,7 @@ class ExecutionPlan:
             "exchange": (None if self.exchange is None
                          else self.exchange.describe()),
             "kernels": list(self.kernels()),
+            "rounds": dict(zip(("carry", "unroll"), self._round_carry())),
             "pallas_calls_per_round": self.pallas_calls_per_round,
             "collectives_per_round": self.collectives_per_round,
         }
@@ -671,19 +674,47 @@ class ExecutionPlan:
             self._cache["step"] = fn
         return fn
 
+    def _round_carry(self) -> Tuple[str, int, _sops.StackedRound]:
+        """How `run()`'s single-chip round scan carries the state:
+        `("stacked", 2, split)` where the op declares a stacked round for
+        the plan's variant — the scan carries the kernel's own operands,
+        stacked once per call, and unrolls by 2 so consecutive rounds
+        ping-pong between two buffers instead of copying the kernel's
+        output back into the carry — else `("dict", 1, split)`, the
+        `WeatherState` itself around `plan.step`."""
+        rc = self._cache.get("round_carry")
+        if rc is None:
+            opdef = self.op_def
+            split = (opdef.stacked_round(self)
+                     if self.mesh is None and opdef.stacked_round is not None
+                     else None)
+            if split is not None:
+                rc = ("stacked", 2, split)
+            else:
+                step = self._step_fn()
+                rc = ("dict", 1, _sops.StackedRound(
+                    pack=lambda state: (state, ()),
+                    step=lambda state, _: step(state),
+                    unpack=lambda state, _: state))
+            self._cache["round_carry"] = rc
+        return rc
+
     def _rounds_fn(self, rounds: int):
         """Jitted scan of `rounds` full rounds (single-chip), cached per
         round count so repeated `run` calls don't re-trace the scan."""
         fn = self._cache.get(("rounds", rounds))
         if fn is None:
-            step = self._step_fn()
+            _, unroll, split = self._round_carry()
 
             @_sops.jit_round(self.program.op, "rounds")
             def fn(state):
-                def body(s, _):
-                    return step(s), ()
-                out, _ = jax.lax.scan(body, state, (), length=rounds)
-                return out
+                carry, consts = split.pack(state)
+
+                def body(c, _):
+                    return split.step(c, consts), ()
+                carry, _ = jax.lax.scan(body, carry, (), length=rounds,
+                                        unroll=unroll)
+                return split.unpack(carry, state)
             self._cache[("rounds", rounds)] = fn
         return fn
 

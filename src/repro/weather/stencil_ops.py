@@ -50,6 +50,7 @@ both), which is how a chain keeps intermediates resident between stages.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -123,6 +124,21 @@ class OperandRide:
 
 
 @dataclasses.dataclass(frozen=True)
+class StackedRound:
+    """A single-chip round split at the kernel's own operand layout.
+
+    `pack(state) -> (carry, consts)` builds the kernel's operands: the
+    carry it rewrites every round and the loop invariants it only reads;
+    `step(carry, consts) -> carry` is the bare kernel launch;
+    `unpack(carry, state) -> WeatherState` lays the carry back out as the
+    state.  `pack`, then `step`, then `unpack` is exactly one `plan.step`."""
+
+    pack: Callable
+    step: Callable
+    unpack: Callable
+
+
+@dataclasses.dataclass(frozen=True)
 class StencilOpDef:
     """A registered stencil operator: footprint declaration + lowerings.
 
@@ -153,7 +169,11 @@ class StencilOpDef:
     * `kstep_vmem_check(program, shards)` -> per-k legality callable for
       `autotune.resolve_k_steps` — ops with their OWN in-kernel k-step
       round (not the fused dycore's) declare how a candidate k's working
-      slab is checked.
+      slab is checked;
+    * `stacked_round(plan)` -> a `StackedRound` for a single-chip plan
+      whose kernel takes field-stacked operands, or None: `run()`'s round
+      scan then carries the state in the kernel's layout and stacks once
+      per call instead of twice per round.
     """
 
     name: str
@@ -185,6 +205,8 @@ class StencilOpDef:
     apply_stage: Optional[Callable] = dataclasses.field(
         default=None, compare=False, repr=False)
     kstep_vmem_check: Optional[Callable] = dataclasses.field(
+        default=None, compare=False, repr=False)
+    stacked_round: Optional[Callable] = dataclasses.field(
         default=None, compare=False, repr=False)
 
     # -- footprint-derived accounting ---------------------------------------
@@ -273,14 +295,13 @@ def _dycore_resolve_tile(variant, compute_grid, dtype, n_fields, ensemble,
 def _dycore_local_step(plan):
     """Single-chip lowering: the periodic-domain kernels at the plan's
     resolved tile/precision/interpret settings.  Every variant is wrapped
-    in ONE jax.jit so a round is a single dispatch (stack/unstack and the
-    per-field loop trace into the same computation)."""
+    in ONE jax.jit so a round is a single dispatch (the per-field loop, or
+    the stacked round's pack, launch and unpack, trace into the same
+    computation)."""
     prog = plan.program
     names, coeff, dt = prog.fields, prog.coeff, prog.dt
     variant, interp = plan.variant, plan.interpret
     ty = plan.tile_ty
-    stack = lambda d: _dycore.stack_state(d, names)
-    unstack = lambda a: _dycore.unstack_state(a, names)
 
     if variant == "unfused":
         @jit_round(prog.op)
@@ -315,28 +336,54 @@ def _dycore_local_step(plan):
                                 tens=state.tens, stage_tens=new_stage)
         return step
 
-    if variant == "whole_state":
-        @jit_round(prog.op)
-        def step(state: WeatherState) -> WeatherState:
-            f_new, stage = fused_ops.fused_step_whole_state(
-                stack(state.fields), state.wcon, stack(state.tens),
-                stack(state.stage_tens), coeff=coeff, dt=dt, ty=ty,
-                interpret=interp)
-            return WeatherState(fields=unstack(f_new), wcon=state.wcon,
-                                tens=state.tens, stage_tens=unstack(stage))
-        return step
-
-    k = plan.k_steps
+    split = _dycore_stacked_round(plan)
 
     @jit_round(prog.op)
     def step(state: WeatherState) -> WeatherState:
-        f_new, stage = fused_ops.fused_step_kstep(
-            stack(state.fields), state.wcon, stack(state.tens),
-            stack(state.stage_tens), k_steps=k, coeff=coeff, dt=dt, ty=ty,
-            interpret=interp, prefetch_w=plan.prefetch_w)
-        return WeatherState(fields=unstack(f_new), wcon=state.wcon,
-                            tens=state.tens, stage_tens=unstack(stage))
+        carry, consts = split.pack(state)
+        return split.unpack(split.step(carry, consts), state)
     return step
+
+
+def _dycore_stacked_round(plan):
+    """The whole-state and k-step rounds split at the kernel's layout: the
+    carry is the field-stacked `(fields, stage_tens)` pair, the invariants
+    the stacked slow tendencies and the staggered velocity
+    `w = wcon + wcon[x+1]` (periodic).  None for the variants that launch
+    per field or run the oracle."""
+    prog = plan.program
+    if plan.variant not in ("whole_state", "kstep"):
+        return None
+    names, coeff, dt = prog.fields, prog.coeff, prog.dt
+    interp, k = plan.interpret, plan.k_steps
+    ny, dtype = prog.grid_shape[1], jnp.dtype(prog.dtype)
+    stack = lambda d: _dycore.stack_state(d, names)
+    unstack = lambda a: _dycore.unstack_state(a, names)
+
+    def pack(state: WeatherState):
+        w = fused_ops.staggered_w(state.wcon)
+        return ((stack(state.fields), stack(state.stage_tens)),
+                (stack(state.tens), w))
+
+    if plan.variant == "whole_state":
+        launch = functools.partial(
+            fused_dycore_whole_state_pallas,
+            ty=fused_ops.snap_ty(plan.tile_ty, ny, dtype))
+    else:
+        launch = functools.partial(
+            fused_dycore_kstep_pallas, k_steps=k,
+            ty=fused_ops.snap_ty_kstep(plan.tile_ty, ny, k, dtype),
+            prefetch_w=plan.prefetch_w)
+
+    def step(carry, consts):
+        (fs, ss), (ts, w) = carry, consts
+        return launch(fs, w, ts, ss, coeff=coeff, dt=dt, interpret=interp)
+
+    def unpack(carry, state: WeatherState) -> WeatherState:
+        fs, ss = carry
+        return WeatherState(fields=unstack(fs), wcon=state.wcon,
+                            tens=state.tens, stage_tens=unstack(ss))
+    return StackedRound(pack=pack, step=step, unpack=unpack)
 
 
 def _dycore_shard_local(plan):
@@ -497,6 +544,7 @@ register_stencil_op(StencilOpDef(
     collectives=_dycore_collectives,
     traffic=_dycore_traffic,
     exchange_model=_dycore_exchange_model,
+    stacked_round=_dycore_stacked_round,
 ))
 
 

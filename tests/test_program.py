@@ -359,3 +359,78 @@ def test_round_plan_depths_and_validation():
     for bad in (0, 4, -1, "2", 2.0):
         with pytest.raises(ValueError, match="round_plan"):
             plan.round_plan(bad)
+
+
+# ---------------------------------------------------------------------------
+# run()'s round scan: the stacked carry
+# ---------------------------------------------------------------------------
+
+_CARRY_GRID = (4, 16, 16)
+
+
+def _carry_program(variant, k_steps=1):
+    if variant == "chain":
+        from repro.weather.pipeline import PipelineProgram, PipelineStage
+        return PipelineProgram(grid_shape=_CARRY_GRID, ensemble=2, stages=(
+            PipelineStage("hadv_upwind"), PipelineStage("vadvc_update"),
+            PipelineStage("hdiff")))
+    return DycoreProgram(grid_shape=_CARRY_GRID, ensemble=2,
+                         variant=variant, k_steps=k_steps)
+
+
+@pytest.mark.parametrize("variant,k,steps,carry", [
+    ("whole_state", 1, 3, "stacked"),      # odd: the unroll's remainder
+    ("whole_state", 1, 4, "stacked"),
+    ("kstep", 2, 5, "stacked"),            # two rounds and a ragged tail
+    ("per_field", 1, 3, "dict"),
+    ("unfused", 1, 3, "dict"),
+    ("chain", 1, 3, "dict"),
+], ids=["whole_state-3", "whole_state-4", "kstep-5", "per_field",
+        "unfused", "chain"])
+def test_run_carry_is_bitwise_repeated_steps(variant, k, steps, carry):
+    """run(state, n) equals its rounds as repeated step() calls (and the
+    tail round's plan) bit for bit, whichever carry the round scan takes;
+    report()["rounds"] names that carry."""
+    from repro.weather.program import compile
+    plan = compile(_carry_program(variant, k))
+    unroll = 2 if carry == "stacked" else 1
+    assert plan.report()["rounds"] == {"carry": carry, "unroll": unroll}
+    st = fields.initial_state(jax.random.PRNGKey(5), _CARRY_GRID,
+                              ensemble=2)
+    want = st
+    for _ in range(steps // plan.k_steps):
+        want = plan.step(want)
+    if steps % plan.k_steps:
+        want = plan.round_plan(steps % plan.k_steps).step(want)
+    got = plan.run(st, steps)
+    for name in fields.PROGNOSTIC:
+        for part in ("fields", "stage_tens"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, part)[name]),
+                np.asarray(getattr(want, part)[name]), err_msg=(part, name))
+
+
+def test_stacked_round_scan_body_is_the_kernel_alone():
+    """The dycore's round scan carries the kernel's stacked operands: its
+    body is the kernel launch (with the free reshapes that fold the field
+    axis into the kernel's batch), no stack, slice or gather of the state,
+    unrolled by 2.  The chain keeps the dict scan: its body is exactly one
+    step()."""
+    from repro.core import trace_stats
+    from repro.weather.program import compile
+    st = fields.initial_state(jax.random.PRNGKey(0), _CARRY_GRID, ensemble=2)
+    dycore = compile(_carry_program("whole_state"))
+    (unroll, body), = trace_stats.scan_bodies(
+        jax.make_jaxpr(dycore._rounds_fn(4))(st))
+    prims = trace_stats.primitive_counts(body, kernels=False)
+    assert unroll == 2
+    assert prims.pop("pallas_call") == 1
+    assert set(prims) <= {"reshape"}, prims
+
+    chain = compile(_carry_program("chain"))
+    (unroll, body), = trace_stats.scan_bodies(
+        jax.make_jaxpr(chain._rounds_fn(4))(st))
+    assert unroll == 1
+    assert (trace_stats.primitive_counts(body, kernels=False)
+            == trace_stats.primitive_counts(
+                jax.make_jaxpr(chain._step_fn())(st), kernels=False))

@@ -15,6 +15,7 @@ compiles: an entry written for a described chip cannot be read back here.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,3 +109,31 @@ def test_2x2_plan_compiles_native(name, topo, no_compile_cache):
     assert "tpu_custom_call" in text
     assert plan.collectives_per_round > 0
     assert "collective-permute" in text
+
+
+def _computation(text, name):
+    """The instruction lines of HLO computation `name` in `text`."""
+    block = re.search(r"^%" + re.escape(name) + r" .*?\n(.*?)^}", text,
+                      re.S | re.M)
+    assert block, name
+    return block.group(1).splitlines()
+
+
+def test_batch_rounds_loop_holds_only_the_kernels(topo, no_compile_cache):
+    """The batch cell's `run(state, 10)`: 11 members at (64, 256, 256),
+    whole-state.  The round loop carries the stacked state, so each trip
+    of the compiled while loop is the two unrolled kernel launches: no
+    stack, relayout copy or unstack of the state inside it."""
+    plan = compile(_program(11, ONE_CHIP["dycore_whole_state"]),
+                   interpret=False)
+    shapes = _state_shapes(SingleDeviceSharding(topo.devices[0]), 11)
+    text = plan._rounds_fn(10).lower(shapes).compile().as_text()
+    (body,) = re.findall(r" while\(.*?body=%([\w.-]+)", text)
+    lines = _computation(text, body)
+    ops = [m.group(2) for m in (re.match(r"\s*(ROOT )?%\S+ = .*? "
+                                         r"([a-z][a-z0-9-]*)\(", line)
+                                for line in lines) if m]
+    kernels = [line for line in lines if "custom-call(" in line]
+    assert len(kernels) == 2
+    assert all("%nero_dycore_whole_state" in line for line in kernels)
+    assert not {"copy", "fusion", "concatenate"} & set(ops), ops
