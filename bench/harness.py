@@ -5,8 +5,11 @@ Everything that belongs to one of them is a file of its own under the
 benchmark's directory, found by that name:
 
 * `configs/<file>.json`: the program as `StencilProgram.to_json()` gives
-  it, the member count, the operands a step must read and write, and the
-  plain reference (`<module>.<step>` of a module beside it);
+  it, the member count, the operands a step must read and write, the
+  plain reference (`<module>.<step>` of a module beside it), and, for a
+  cell on more than one chip, its decomposition: `"mesh": {"shape": [py,
+  px], "axes": [ax_y, ax_x]}`, y and x over the mesh's two axes, z and the
+  ensemble whole on every chip, `py * px` the workload's `chips`;
 * `traffic/<name>.json`: the parameters of the mix (`traffic.py`);
 * `metrics/<name>.py`: a per-layer metric's reader, `read(run)`, which
   returns a number or None where it finds nothing to read;
@@ -23,6 +26,7 @@ import dataclasses
 import glob
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
@@ -64,6 +68,30 @@ def _reports(metric: Dict, workload: str) -> bool:
     return workload in metric.get("workloads", [workload])
 
 
+def _check_mesh(config: Dict, chips: int, workload: str) -> None:
+    """A cell on several chips says how it uses them: its configuration's
+    `mesh` has two axes, y and x, whose sizes multiply to `chips`."""
+    mesh = config.get("mesh")
+    if mesh is None:
+        if chips != 1:
+            raise ValueError(f"{workload}: a cell on {chips} chips needs a "
+                             f"'mesh' in its configuration, the "
+                             f"decomposition it runs")
+        return
+    shape, axes = mesh.get("shape"), mesh.get("axes")
+    if (not isinstance(shape, list) or len(shape) != 2
+            or not all(isinstance(n, int) and n > 0 for n in shape)
+            or not isinstance(axes, list) or len(axes) != 2
+            or not all(isinstance(a, str) and a for a in axes)
+            or axes[0] == axes[1]):
+        raise ValueError(f"{workload}: mesh {mesh!r} is not {{'shape': "
+                         f"[py, px], 'axes': [ax_y, ax_x]}}")
+    if math.prod(shape) != chips:
+        raise ValueError(f"{workload}: mesh shape {shape} holds "
+                         f"{math.prod(shape)} chips, the cell asks for "
+                         f"{chips}")
+
+
 def load_cell(workload: str, root: str = ROOT) -> Cell:
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         spec = json.load(f)
@@ -72,6 +100,7 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     c = _by_name(spec["configs"], w["config"], "configuration")
     with open(os.path.join(root, c["file"])) as f:
         config = json.load(f)
+    _check_mesh(config, w["chips"], workload)
     mix = traffic.load(os.path.join(bench_dir, "traffic",
                                     f"{w['traffic']}.json"))
     with open(os.path.join(bench_dir, "limits", f"{workload}.json")) as f:
@@ -163,14 +192,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              dtype: Optional[str] = None) -> Dict[str, Any]:
     """Set up, measure, check; returns the result line as a dict.
 
-    `devices` are the chips to report (None: the run is off a chip, as in
-    the tests, and reports no device numbers); `dtype` replaces the
-    configuration's precision, for the lower-precision control."""
+    `devices` are the chips the cell runs on and reports (None: the run is
+    off a chip, as in the tests, runs on JAX's first `chips` devices and
+    reports no device numbers); `dtype` replaces the configuration's
+    precision, for the lower-precision control."""
     cell = load_cell(workload, root)
     kind = devices[0].device_kind if devices else "none"
     if devices:
         yardstick.peaks_for(kind)
-    run = window.DRIVERS[cell.traffic["kind"]](cell, seed, dtype=dtype)
+    run = window.DRIVERS[cell.traffic["kind"]](
+        cell, seed, devices or jax.devices()[:cell.chips], dtype=dtype)
     setup_s = time.perf_counter() - t_start
     trace_dir = os.path.join(WORK, "trace")
     if trace:
@@ -186,14 +217,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
     device = {"platform": devices[0].platform if devices else "none",
-              "kind": kind, "count": len(jax.devices())}
+              "kind": kind, "count": len(jax.devices()),
+              "mesh": cell.config.get("mesh", {}).get("shape")}
     if devices:
         device["memory_peak_bytes"] = max(
             d.memory_stats()["peak_bytes_in_use"] for d in devices)
     out = run.outcome(cell.bench_dir)
     if trace:
         reduced = trace_reduce.reduce(_newest_trace(trace_dir),
-                                      n_devices=len(devices or [0]))
+                                      n_devices=cell.chips)
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
         metrics = per_layer_metrics(Run(cell=cell, counters=out.counters,

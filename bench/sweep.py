@@ -37,9 +37,9 @@ def main(argv=None) -> int:
     import window
     import yardstick
     cell = harness.load_cell(args.workload)
-    harness.require_devices(cell.chips)
+    devices = harness.require_devices(cell.chips)
     harness.use_compile_cache()
-    serve = window.Serve(cell, args.seed)
+    serve = window.Serve(cell, args.seed, devices)
     print(json.dumps({"setup_s": time.perf_counter() - T_START}), flush=True)
     base = dict(cell.traffic)
     for interval in args.intervals:

@@ -8,6 +8,7 @@ from __future__ import annotations
 import json
 import os
 import shutil
+import subprocess
 import sys
 
 TESTS = os.path.dirname(os.path.abspath(__file__))
@@ -19,6 +20,7 @@ for p in (BENCH, os.path.join(ROOT, "src")):
 
 GRID = [4, 16, 16]
 MEMBERS = 3
+MESH_CELL = "dycore-2x2-batch"
 
 
 def small_config(name: str, members: int = MEMBERS) -> dict:
@@ -66,3 +68,47 @@ def small_root(tmp) -> str:
 def write(base: str, rel: str, obj) -> None:
     with open(os.path.join(base, rel), "w") as f:
         json.dump(obj, f)
+
+
+def add_mesh_cell(root: str, shape=(2, 2), chips: int = 4,
+                  name: str = MESH_CELL) -> None:
+    """Adds to a small root a batch cell of the real dycore configuration
+    decomposed over a `shape` mesh (None: no `mesh` key) on `chips` chips,
+    with the limits of `dycore-e11-batch`: files and entries only."""
+    bench = os.path.join(root, "bench")
+    cfg = small_config("nero_dycore_256x256x64")
+    cfg["name"] = "nero_dycore_mesh"
+    if shape is not None:
+        cfg["mesh"] = {"shape": list(shape), "axes": ["data", "model"]}
+    write(bench, "configs/nero_dycore_mesh.json", cfg)
+    shutil.copy(os.path.join(bench, "limits", "dycore-e11-batch.json"),
+                os.path.join(bench, "limits", f"{name}.json"))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "nero_dycore_mesh", "source": "test",
+                            "file": "bench/configs/nero_dycore_mesh.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": name, "config": "nero_dycore_mesh",
+                              "traffic": "batch_forecast100",
+                              "chips": chips, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "dycore-e11-batch" in m.get("workloads", ()):
+            m["workloads"].append(name)
+    write(root, "BENCHMARK.json", spec)
+
+
+def on_four_devices(code: str, *args: str, timeout: float = 900) -> dict:
+    """Runs `code` with `args` in a fresh process whose JAX has four CPU
+    devices, with the benchmark's modules importable, and returns the JSON
+    object it prints as its last line of standard output."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([TESTS, BENCH,
+                                           os.path.join(ROOT, "src")]))
+    r = subprocess.run([sys.executable, "-c", code, *args], env=env,
+                       cwd=ROOT,
+                       capture_output=True, text=True, timeout=timeout)
+    if r.returncode != 0 or not r.stdout.strip():
+        raise AssertionError(f"exit {r.returncode}\n{r.stdout[-2000:]}\n"
+                             f"{r.stderr[-4000:]}")
+    return json.loads(r.stdout.strip().splitlines()[-1])

@@ -3,6 +3,7 @@ and BENCHMARK.json entries, with no edit to a file that is there."""
 
 import json
 import os
+import shutil
 import time
 
 import pytest
@@ -66,6 +67,39 @@ def test_a_new_cell_mix_and_metric_need_no_edit(tmp_path):
     assert harness.per_layer_metrics(run) == {
         "steps_done.tiny": {"value": 3 * line["attempted"],
                             "unit": "steps"}}
+    after = _snapshot(root)
+    assert all(after[k] == v for k, v in before.items())
+
+
+# `bench/run.py` in a checkout of the benchmark, with the look for a chip
+# skipped: the run is on JAX's first `chips` devices and reports none.
+RUN_PY = r"""
+import os, sys
+root = sys.argv[1]
+sys.path.insert(0, os.path.join(root, "bench"))
+import harness, run
+harness.require_devices = lambda chips: None
+sys.exit(run.main(["--workload", sys.argv[2], "--seed", str(2**33 + 99),
+                   "--seconds", "0.5", "--trace", "0"]))
+"""
+
+
+def test_a_cell_on_a_2x2_mesh_needs_no_edit(tmp_path):
+    """A configuration with a `mesh` and a 4-chip workload entry run
+    through `bench/run.py` by files and entries alone."""
+    root = bt.small_root(tmp_path)
+    for f in os.listdir(bt.BENCH):
+        if f.endswith(".py"):
+            shutil.copy(os.path.join(bt.BENCH, f),
+                        os.path.join(root, "bench"))
+    before = _snapshot(root)
+    del before["BENCHMARK.json"]      # entries are added, none changed
+    bt.add_mesh_cell(root)
+    line = bt.on_four_devices(RUN_PY, root, bt.MESH_CELL)
+    assert line["correct"], line["checks"]
+    assert line["device"]["mesh"] == [2, 2]
+    assert set(line["metrics"]) == {"gridpoint_steps_per_s", "setup_s"}
+    assert list(line)[-1] == "checks"
     after = _snapshot(root)
     assert all(after[k] == v for k, v in before.items())
 

@@ -45,6 +45,9 @@ def test_busy_time_is_averaged_over_the_chips_used():
            "/device:TPU:2": []}
     assert tr.summarize(spans, ops, 2).busy_s == pytest.approx(75e-9)
     assert tr.summarize(spans, ops, 1).busy_s == pytest.approx(100e-9)
+    # So is each operation's time: on the chips used they sum to busy_s.
+    assert tr.summarize(spans, ops, 2).top_ops == [["x",
+                                                    pytest.approx(75e-9)]]
 
 
 def test_a_window_with_no_device_operation_is_an_error():

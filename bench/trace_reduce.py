@@ -7,7 +7,8 @@ innermost benchmark host span it fell in.
 The window is the benchmark's `bench.window` span on the host.  Busy time
 is the union of the intervals in which an operation ran on a device (the
 `XLA Ops` line of each `/device:TPU:<n>` plane), inside the window,
-averaged over the chips used.
+averaged over the chips used; so is each operation's time, so that on
+several chips the operations' times still add up to the busy time.
 """
 
 from __future__ import annotations
@@ -40,7 +41,7 @@ def profile_options():
 class TraceSummary:
     busy_s: float                           # mean over the chips used
     window_s: float
-    top_ops: List[List]                     # [[op, self seconds], ...]
+    top_ops: List[List]                     # [[op, self s per chip], ...]
     idle_gaps: List[List]                   # [[host span, seconds], ...]
 
     @property
@@ -131,7 +132,7 @@ def summarize(spans: Sequence[Tuple[float, float, str]],
     top = sorted(totals.items(), key=lambda kv: -kv[1])
     return TraceSummary(
         busy_s=busy_ns * 1e-9, window_s=(hi - lo) * 1e-9,
-        top_ops=[[n, t * 1e-9] for n, t in top],
+        top_ops=[[n, t / n_devices * 1e-9] for n, t in top],
         idle_gaps=[[n, t * 1e-9] for t, n in idle])
 
 

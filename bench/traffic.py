@@ -23,9 +23,8 @@ A traffic file (`bench/traffic/<name>.json`) holds parameters only.  Its
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,9 +53,8 @@ def key_data(seed: int) -> np.ndarray:
         2, dtype=np.uint32)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
-def _make_state(key, grid: Tuple[int, int, int], members: int,
-                names: Tuple[str, ...], dtype: str):
+def _state_of(key, grid: Tuple[int, int, int], members: int,
+              names: Tuple[str, ...], dtype: str):
     """Band-limited random fields (random values on a grid 8x coarser,
     trilinearly resized): fields ~N(0, 1), slow tendencies 0.01x, the
     vertical velocity 0.15x (a well-conditioned implicit solve), and zero
@@ -78,13 +76,22 @@ def _make_state(key, grid: Tuple[int, int, int], members: int,
             "wcon": smooth(keys[-1], 0.15)}
 
 
-def make_state(seed: int, program: Dict, members: int) -> Dict:
+_make_state = jax.jit(_state_of, static_argnums=(1, 2, 3, 4))
+
+
+def make_state(seed: int, program: Dict, members: int,
+               sharding: Optional[jax.sharding.Sharding] = None) -> Dict:
     """The initial state of `members` members, made on the device in one
     jitted call, in the program's dtype: {fields, tens, stage_tens: {name:
-    (members, nz, ny, nx)}, wcon: (members, nz, ny, nx)}."""
-    return _make_state(jnp.asarray(key_data(seed)),
-                       tuple(program["grid_shape"]), int(members),
-                       tuple(program["fields"]), program["dtype"])
+    (members, nz, ny, nx)}, wcon: (members, nz, ny, nx)}.  With `sharding`
+    (every leaf's, on a mesh) the call makes each leaf where it lives, so
+    no chip holds more than its share; the values are those made on one
+    device, bit for bit (JAX's partitionable threefry)."""
+    make = (_make_state if sharding is None else
+            jax.jit(_state_of, static_argnums=(1, 2, 3, 4),
+                    out_shardings=sharding))
+    return make(jnp.asarray(key_data(seed)), tuple(program["grid_shape"]),
+                int(members), tuple(program["fields"]), program["dtype"])
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,10 +19,11 @@ import importlib.util
 import math
 import os
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import traffic as _traffic
 import yardstick
@@ -58,14 +59,20 @@ def _leaves(d: Dict, groups=LEAF_GROUPS + ("wcon",)) -> Dict[str, Any]:
     return out
 
 
-@jax.jit
-def _member_of(d: Dict, m) -> Dict:
-    return jax.tree_util.tree_map(
+@functools.partial(jax.jit, static_argnums=2)
+def _member_of(d: Dict, m, sharding) -> Dict:
+    out = jax.tree_util.tree_map(
         lambda a: jax.lax.dynamic_index_in_dim(a, m, keepdims=False), d)
+    if sharding is not None:
+        out = jax.lax.with_sharding_constraint(out, sharding)
+    return out
 
 
-def _member(d: Dict, m: int) -> Dict:
-    return _member_of(d, jnp.int32(m))
+def _member(d: Dict, m: int, sharding=None) -> Dict:
+    """Member `m`'s leaves; on a mesh, laid out as `sharding` (the
+    ensemble's without its member axis), so no chip holds more than its
+    share of the member."""
+    return _member_of(d, jnp.int32(m), sharding)
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -95,17 +102,18 @@ def load_reference(bench_dir: str, spec: str) -> Callable:
 class Reference:
     """Advances one member (leaves without the ensemble axis) by `n` steps
     of the configuration's plain reference, in float32: one compiled
-    program for every step count."""
+    program for every step count.  With `sharding` (a member's on the
+    mesh) XLA partitions the reference and its result stays there."""
 
-    def __init__(self, bench_dir: str, config: Dict):
+    def __init__(self, bench_dir: str, config: Dict, sharding=None):
         step = load_reference(bench_dir, config["reference"])
         coeff, dt = config["program"]["coeff"], config["program"]["dt"]
 
-        @jax.jit
         def run(state, n):
             return jax.lax.fori_loop(0, n, lambda _, s: step(s, coeff, dt),
                                      state)
-        self._run = run
+        self._run = (jax.jit(run) if sharding is None
+                     else jax.jit(run, out_shardings=sharding))
 
     def __call__(self, member: Dict, n: int) -> Dict:
         return self._run(_cast(member, jnp.float32), jnp.int32(n))
@@ -148,13 +156,30 @@ def program_for(config: Dict, ensemble: int, dtype: Optional[str] = None):
     return StencilProgram.from_json(d)
 
 
+def mesh_for(config: Dict, devices: Sequence[Any]) -> Optional[Mesh]:
+    """The configuration's decomposition over the cell's chips, laid on
+    the physical torus; None for a cell on one chip."""
+    spec = config.get("mesh")
+    if spec is None:
+        return None
+    from jax.experimental import mesh_utils
+    return Mesh(mesh_utils.create_device_mesh(spec["shape"], devices),
+                tuple(spec["axes"]))
+
+
 class Batch:
     """Back-to-back forecasts of `forecast_steps` steps, all members
     together, each advanced by `run(state, steps_per_call)` calls that
     continue from the last state, each call timed to `block_until_ready`.
-    Every forecast starts from the initial state made from the seed."""
+    Every forecast starts from the initial state made from the seed.
 
-    def __init__(self, cell, seed: int, dtype: Optional[str] = None):
+    With a `mesh` in the configuration the plan is the program's
+    distributed one on that mesh (y and x sharded, z and the ensemble
+    whole on each chip), and the initial state and the check's arrays
+    live sharded as its `state_spec` says."""
+
+    def __init__(self, cell, seed: int, devices: Sequence[Any],
+                 dtype: Optional[str] = None):
         from repro.weather.program import compile
         self.cell, self.seed = cell, seed
         self.cfg, self.n = cell.config, cell.traffic["steps_per_call"]
@@ -164,11 +189,22 @@ class Batch:
             raise ValueError(f"forecast_steps must be a positive multiple "
                              f"of steps_per_call: {cell.traffic}")
         self.dtype = dtype or self.cfg["program"]["dtype"]
-        self.plan = compile(program_for(self.cfg, self.cfg["members"],
-                                        self.dtype))
+        prog = program_for(self.cfg, self.cfg["members"], self.dtype)
+        mesh = mesh_for(self.cfg, devices)
+        if mesh is None:
+            self.plan = compile(prog)
+            self.sharding = self.member_sharding = None
+        else:
+            ax_y, ax_x = mesh.axis_names
+            self.plan = compile(prog, mesh=mesh, ax_e=None, ax_y=ax_y,
+                                ax_x=ax_x)
+            spec = self.plan.state_spec
+            self.sharding = NamedSharding(mesh, spec)
+            self.member_sharding = NamedSharding(mesh, P(*spec[1:]))
         self.x0 = _weather_state(_cast(
             _traffic.make_state(seed, self.cfg["program"],
-                                self.cfg["members"]), self.dtype))
+                                self.cfg["members"], self.sharding),
+            self.dtype))
         jax.block_until_ready(self.plan.run(self.x0, self.n))
 
     def window(self, seconds: float) -> None:
@@ -199,17 +235,20 @@ class Batch:
         with the reference, member by member, each from its own input."""
         cfg, members = self.cfg, self.cfg["members"]
         self.plan = None
-        ref = Reference(bench_dir, cfg)
+        on = self.member_sharding
+        ref = Reference(bench_dir, cfg, on)
         x0 = _as_dict(self.x0)
         last_in, last_out = _as_dict(self.last_in), _as_dict(self.last_out)
         first_gap = last_gap = 0.0
         for m in range(members):
-            want = ref(_member(x0, m), self.n)
-            first_gap = _worse(first_gap, gap(_member(self.first_out, m),
+            want = ref(_member(x0, m, on), self.n)
+            first_gap = _worse(first_gap, gap(_member(self.first_out, m, on),
                                               want, ("fields", "stage_tens")))
-            want = ref(_member(last_in, m), self.n)
-            last_gap = _worse(last_gap, gap(_member(last_out, m), want,
+            del want    # one member's reference at a time on the chips
+            want = ref(_member(last_in, m, on), self.n)
+            last_gap = _worse(last_gap, gap(_member(last_out, m, on), want,
                                             LEAF_GROUPS + ("wcon",)))
+            del want
         steps = self.calls * self.n
         work = steps * yardstick.gridpoints(cfg)
         return Outcome(
@@ -229,10 +268,16 @@ def _worse(a: float, b: float) -> float:
 class Serve:
     """An open loop of ensemble bursts into a `ForecastEngine` with the
     configuration's `slots` (default: one per member).  Latency runs from
-    each request's due time to the moment the host sees its result."""
+    each request's due time to the moment the host sees its result.  The
+    engine runs on JAX's default device, the cell's one chip."""
 
-    def __init__(self, cell, seed: int, dtype: Optional[str] = None):
+    def __init__(self, cell, seed: int, devices: Sequence[Any],
+                 dtype: Optional[str] = None):
         from repro.serve.forecast import ForecastEngine
+        if "mesh" in cell.config:
+            raise ValueError(f"{cell.name}: the served window runs one chip; "
+                             f"a configuration with a 'mesh' is refused, "
+                             f"not run on one chip")
         self.cell, self.seed = cell, seed
         self.cfg, self.traffic = cell.config, cell.traffic
         self.dtype = dtype or self.cfg["program"]["dtype"]

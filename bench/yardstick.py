@@ -113,17 +113,21 @@ def rel_gap(diff_norms: Dict[str, float], ref_norms: Dict[str, float]
 def step_roofline(run) -> Optional[float]:
     """The whole step's share of the HBM roofline, in %: the compulsory
     bytes of the grid-point-steps completed in the traced window over the
-    device's busy time in it, over the chip's peak bandwidth."""
+    device's busy time in it, over the peak bandwidth of the cell's chips.
+    On several chips the bytes are all of them, the busy time is the mean
+    over the chips, and the peak is `chips` times one chip's."""
     steps = run.counters.get("gridpoint_steps")
     if run.trace is None or not steps:
         return None
     moved = compulsory_bytes_per_point_step(run.config) * steps
-    return 100.0 * moved / run.trace.busy_s / run.peaks["hbm_bytes_per_s"]
+    return 100.0 * moved / run.trace.busy_s / (
+        run.cell.chips * run.peaks["hbm_bytes_per_s"])
 
 
 def idle_share(run) -> Optional[float]:
     """The traced window's share, in %, in which no operation ran on the
-    device."""
+    device.  On several chips, the mean over the cell's chips (busy time
+    averaged by `trace_reduce.summarize(n_devices=chips)`)."""
     if run.trace is None:
         return None
     return 100.0 * run.trace.idle_share
